@@ -14,9 +14,6 @@ Sections:
     ring slots, never control flow or extra collectives).
 """
 import json
-import os
-import subprocess
-import sys
 import textwrap
 
 import jax
@@ -25,10 +22,9 @@ from repro.core import TopK, make_topology
 from repro.core.choco_gossip import run_choco_stale_gossip
 from repro.comm.schedule import compile_schedule
 from repro.comm.async_gossip import StalenessProcess
-from .common import time_fn, emit
+from .common import time_fn, emit, run_cpu_child
 
 N, D, STEPS = 8, 256, 300
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def staleness_rate():
@@ -76,7 +72,8 @@ def hlo_audit():
 
         n, d = 8, 4096
         sched = compile_schedule(make_topology("ring", n))
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8, 1), ("data", "model"))
         comp = TopK(fraction=0.05)
         x0 = jax.random.normal(jax.random.PRNGKey(0), (n, d))
         R = sched.n_rounds
@@ -106,14 +103,8 @@ def hlo_audit():
             out[f"async_tau{tau}"] = n_tau
         print(json.dumps(out))
     """)
-    env = dict(os.environ, PYTHONPATH=SRC)
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", script], env=env,
-                       capture_output=True, text=True, timeout=1800)
-    if r.returncode != 0:
-        emit("async/hlo_audit", 0.0, f"ERROR:{r.stderr[-200:]}")
-        return
-    out = json.loads(r.stdout.strip().splitlines()[-1])
+    stdout = run_cpu_child("async/hlo_audit", script)
+    out = json.loads(stdout.strip().splitlines()[-1])
     base = out["linkfail"]
     for name, cnt in out.items():
         if name == "linkfail":

@@ -36,7 +36,8 @@ def _make_trainer(state_dtype: str):
     from repro.models import build_model
     from repro.train.trainer import DecentralizedTrainer
     from repro.optim import momentum_sgd, constant_schedule
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_config("qwen3-1.7b", smoke=True)
     tr = DecentralizedTrainer(
         model=build_model(cfg),

@@ -13,21 +13,17 @@ Three views:
 Methodology notes live in EXPERIMENTS.md §Wire audit.
 """
 import json
-import os
-import subprocess
-import sys
 import textwrap
 
 import jax
 
 from repro.core import TopK, RandK, QSGD, Identity
-from .common import emit
+from .common import emit, run_cpu_child
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def analytic():
-    d = 2_030_000_000          # qwen3-1.7b-scale parameter vector
+    d = 1_720_000_000          # qwen3-1.7b-scale parameter vector (tied)
     for name, comp in (("exact", Identity()),
                        ("qsgd16", QSGD(16)),
                        ("rand1pct", RandK(fraction=0.01)),
@@ -89,7 +85,8 @@ def compiled():
         from repro.launch.specs import train_batch_specs
         from repro.analysis.roofline import parse_collectives
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("qwen3-1.7b", smoke=True)
         m = build_model(cfg)
         out = {}
@@ -112,14 +109,8 @@ def compiled():
                          "collective_count": sum(st.counts.values())}
         print(json.dumps(out))
     """)
-    env = dict(os.environ, PYTHONPATH=SRC)
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", script], env=env,
-                       capture_output=True, text=True, timeout=1800)
-    if r.returncode != 0:
-        emit("collectives/compiled", 0.0, f"ERROR:{r.stderr[-200:]}")
-        return
-    out = json.loads(r.stdout.strip().splitlines()[-1])
+    stdout = run_cpu_child("collectives/compiled", script)
+    out = json.loads(stdout.strip().splitlines()[-1])
     base = out["plain"]["permute_bytes"] or 1.0
     for mode, v in out.items():
         emit(f"collectives/compiled_{mode}", 0.0,

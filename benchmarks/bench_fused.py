@@ -31,15 +31,12 @@ root (schema in the JSON itself) plus CSV rows.
 """
 import json
 import os
-import subprocess
-import sys
 import textwrap
 
 from repro.analysis.hlo_audit import STREAM_THRESHOLD
 
-from .common import HBM_BW, emit
+from .common import HBM_BW, emit, run_cpu_child
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 OUT_JSON = os.path.join(os.path.dirname(__file__), "..", "BENCH_fused.json")
 
 
@@ -148,15 +145,8 @@ _SCRIPT = textwrap.dedent("""
 
 def fused_audit():
     """Run the subprocess audit and emit CSV rows + BENCH_fused.json."""
-    env = dict(os.environ, PYTHONPATH=SRC + os.pathsep
-               + os.path.join(SRC, ".."))
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
-                       capture_output=True, text=True, timeout=1800)
-    if r.returncode != 0:
-        emit("fused/audit", 0.0, f"ERROR:{r.stderr[-200:]}")
-        return None
-    line = [l for l in r.stdout.splitlines()
+    stdout = run_cpu_child("fused/audit", _SCRIPT)
+    line = [l for l in stdout.splitlines()
             if l.startswith("BENCH_FUSED_JSON=")][-1]
     out = json.loads(line.split("=", 1)[1])
     for name in ("jnp", "pallas"):

@@ -25,13 +25,10 @@ Sections:
 """
 import json
 import os
-import subprocess
-import sys
 import textwrap
 
-from .common import emit
+from .common import emit, run_cpu_child
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 OUT_JSON = os.path.join(os.path.dirname(__file__), "..",
                         "BENCH_overlap.json")
 
@@ -88,15 +85,8 @@ def overlap_audit():
     rows + BENCH_overlap.json."""
     from repro.analysis.invariants import CONTEXT_VARS, assert_invariant
 
-    env = dict(os.environ, PYTHONPATH=SRC + os.pathsep
-               + os.path.join(SRC, ".."))
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
-                       capture_output=True, text=True, timeout=1800)
-    if r.returncode != 0:
-        emit("overlap/audit", 0.0, f"ERROR:{r.stderr[-200:]}")
-        return None
-    line = [l for l in r.stdout.splitlines()
+    stdout = run_cpu_child("overlap/audit", _SCRIPT)
+    line = [l for l in stdout.splitlines()
             if l.startswith("BENCH_OVERLAP_JSON=")][-1]
     out = json.loads(line.split("=", 1)[1])
     for name, rec in out.items():

@@ -17,13 +17,11 @@ Sections:
 """
 import json
 import os
-import subprocess
 import sys
 import textwrap
 
-from .common import emit, time_fn
+from .common import emit, time_fn, run_cpu_child
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 TESTS = os.path.join(os.path.dirname(__file__), "..", "tests")
 OUT_JSON = os.path.join(os.path.dirname(__file__), "..",
                         "BENCH_scenarios.json")
@@ -87,7 +85,8 @@ _AUDIT = textwrap.dedent("""
 
     n, d, tau = 8, 4096, 2
     sched = compile_schedule(make_topology("ring", n))
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8, 1), ("data", "model"))
     comp = TopK(fraction=0.05)
     x0 = jax.random.normal(jax.random.PRNGKey(0), (n, d))
     R = sched.n_rounds
@@ -107,14 +106,8 @@ _AUDIT = textwrap.dedent("""
 
 def hlo_audit():
     """Run the subprocess parity audit; returns the straggler record."""
-    env = dict(os.environ, PYTHONPATH=SRC)
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", _AUDIT], env=env,
-                       capture_output=True, text=True, timeout=1800)
-    if r.returncode != 0:
-        emit("scenarios/hlo_audit", 0.0, f"ERROR:{r.stderr[-200:]}")
-        return None
-    line = [l for l in r.stdout.splitlines()
+    stdout = run_cpu_child("scenarios/hlo_audit", _AUDIT)
+    line = [l for l in stdout.splitlines()
             if l.startswith("BENCH_SCENARIOS_JSON=")][-1]
     rec = json.loads(line.split("=", 1)[1])
     emit("scenarios/hlo_straggler", 0.0,
@@ -129,8 +122,6 @@ def run():
     """Benchmark entry point (python -m benchmarks.run)."""
     skew = skew_sweep()
     straggler = hlo_audit()
-    if straggler is None:
-        return
     out = {"straggler": straggler,
            "skew": {k: {"final_loss": round(v["final_loss"], 4),
                         "consensus_dist": round(v["consensus_dist"], 4)}

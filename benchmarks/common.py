@@ -1,4 +1,8 @@
-"""Shared benchmark utilities: timing + CSV emission + roofline constants."""
+"""Shared benchmark utilities: timing + CSV emission + roofline constants +
+the CPU child process the HLO audits run in."""
+import os
+import subprocess
+import sys
 import time
 
 import jax
@@ -25,3 +29,24 @@ def time_fn(fn, *args, iters=3, warmup=1, **kw):
 
 def emit(name, us_per_call, derived):
     print(f"{name},{us_per_call:.1f},{derived}")
+
+
+SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+
+
+def run_cpu_child(name, script, *, timeout=1800):
+    """Run an HLO-audit ``script`` in a child process and return its stdout.
+
+    The audits count ops in CPU-compiled HLO by design, so the child is
+    pinned to the CPU (``JAX_PLATFORMS=cpu``) and never contends for an
+    accelerator the parent may hold; it sets its own host device count.
+    A failed child ends the bench with a nonzero exit."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=SRC + os.pathsep + os.path.join(SRC, ".."))
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", script], env=env,
+                       capture_output=True, text=True, timeout=timeout)
+    if r.returncode != 0:
+        raise SystemExit(f"{name}: audit child exited {r.returncode}\n"
+                         f"{r.stderr[-2000:]}")
+    return r.stdout

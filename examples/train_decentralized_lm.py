@@ -62,8 +62,9 @@ def main():
           f"nodes={args.nodes} mode={args.mode} "
           f"compressor={args.compressor}@{args.fraction}")
 
-    mesh = jax.make_mesh((args.nodes, N_DEVICES // args.nodes),
-                         ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((args.nodes, N_DEVICES // args.nodes),
+                     ("data", "model"))
     trainer = DecentralizedTrainer(
         model=model,
         choco=ChocoConfig(compressor=args.compressor,

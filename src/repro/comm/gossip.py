@@ -61,14 +61,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core.compression import Compressor
 from repro.comm.schedule import GossipSchedule
 
-# jax.shard_map landed in 0.5.x; on 0.4.x the same function lives under
-# jax.experimental.shard_map.  Resolve once at import time.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map
-
-
 def _leaf_keys(key, n: int, salt: int):
     return jax.random.split(jax.random.fold_in(key, salt), n)
 
@@ -975,7 +967,7 @@ def make_gossip_exchange(*, mode: str, mesh, state_specs, axis,
             compressor=compressor, gamma=gamma, gossip_steps=gossip_steps,
             pack_align=pack_align,
             leaf_routes=_leaf_routes(state_specs, axes))
-        return shard_map(
+        return jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(P(), state_specs, state_specs, state_specs,
                       weight_specs),
@@ -1028,7 +1020,7 @@ def make_gossip_exchange(*, mode: str, mesh, state_specs, axis,
         tau = process.max_staleness
         hat_specs = [state_specs] * (1 + tau)
         s_specs = [state_specs] * (R * (1 + tau))
-        return shard_map(
+        return jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(P(), state_specs, hat_specs, s_specs),
             out_specs=(state_specs, hat_specs, s_specs),
@@ -1047,7 +1039,7 @@ def make_gossip_exchange(*, mode: str, mesh, state_specs, axis,
         hat_specs = (state_specs if process.kind == "linkfail"
                      else [state_specs] * R)
         s_specs = [state_specs] * R
-        return shard_map(
+        return jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(P(), state_specs, hat_specs, s_specs),
             out_specs=(state_specs, hat_specs, s_specs),
@@ -1082,15 +1074,12 @@ def make_gossip_exchange(*, mode: str, mesh, state_specs, axis,
     else:
         raise ValueError(mode)
 
-    smap_kwargs = {}
-    if (resolved_backend == "pallas"
-            and not kdispatch.shard_map_check_rep("pallas")):
-        # jax 0.4.x shard_map has no replication rule for pallas_call;
-        # the exchange's specs carry no replicated outputs anyway
-        smap_kwargs["check_rep"] = False
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), state_specs, state_specs, state_specs),
         out_specs=(state_specs, state_specs, state_specs),
-        **smap_kwargs,
+        # a pallas_call's out_shape carries no varying-mesh-axes (vma)
+        # type, which check_vma=True rejects; the check only guards
+        # replication typing and never changes numerics
+        check_vma=(resolved_backend != "pallas"),
     )

@@ -80,6 +80,7 @@ class ModelConfig:
     frontend: Optional[FrontendConfig] = None
     # numerics / training
     tie_embeddings: bool = False
+    scale_embed: bool = False               # gemma: token embedding * sqrt(D)
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
@@ -290,8 +291,8 @@ class ChocoConfig:
     # mode='choco', a single static topology, and no topology_process.
     pipeline_gossip: bool = False
     # kernel backend for the gossip hot path (kernels/dispatch.py):
-    # 'auto' probes the toolchain and picks the fused Pallas kernels when
-    # they can run compiled (TPU), 'pallas'/'jnp' force.  Never part of the
+    # 'auto' picks the fused Pallas kernels on a TPU and jnp elsewhere,
+    # 'pallas'/'jnp' force.  Never part of the
     # checkpoint fingerprint: flipping it changes neither the state layout
     # nor the wire bytes, so resumes are backend-portable.
     kernel_backend: str = "auto"

@@ -1,4 +1,5 @@
-"""Qwen3-1.7B — dense decoder with QK-norm and GQA [hf:Qwen/Qwen3-8B]."""
+"""Qwen3-1.7B — dense decoder with QK-norm, GQA and a tied embedding
+[hf:Qwen/Qwen3-1.7B config.json; Qwen3 technical report, model table]."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -6,8 +7,8 @@ CONFIG = ModelConfig(
     n_layers=28, d_model=2048, n_heads=16, n_kv_heads=8, head_dim=128,
     d_ff=6144, vocab_size=151936,
     qk_norm=True, mlp_type="swiglu", rope_theta=1_000_000.0,
-    remat="dots", loss_chunk=512,
-    source="hf:Qwen/Qwen3-8B",
+    tie_embeddings=True, remat="dots", loss_chunk=512,
+    source="hf:Qwen/Qwen3-1.7B",
 )
 
 SMOKE_CONFIG = ModelConfig(
@@ -15,5 +16,5 @@ SMOKE_CONFIG = ModelConfig(
     n_layers=2, d_model=256, n_heads=4, n_kv_heads=2, head_dim=64,
     d_ff=512, vocab_size=512,
     qk_norm=True, mlp_type="swiglu",
-    source="hf:Qwen/Qwen3-8B",
+    source="hf:Qwen/Qwen3-1.7B",
 )

@@ -16,3 +16,14 @@ Layout (the xformers-style kernel/reference discipline):
 OPTIONAL layer by repo convention: add kernels only for compute
 hot-spots the reproduction actually optimizes.
 """
+
+
+def resolve_interpret(interpret):
+    """Interpret mode for a kernel call: compiled on a TPU, interpreted on
+    any other backend, unless the caller says otherwise.  Every kernel
+    entry point defaults to ``interpret=None`` and resolves it here, so on
+    a TPU no caller gets the interpreter by omission."""
+    if interpret is None:
+        import jax
+        return jax.default_backend() != "tpu"
+    return bool(interpret)

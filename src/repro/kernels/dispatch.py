@@ -11,150 +11,35 @@ implementation runs them:
   fusion decides how many HBM passes the EF update costs).
 * ``"pallas"`` — the fused kernels in ``kernels/qsgd.py`` /
   ``kernels/ef_update.py``: one launch per bucket per direction.
-* ``"auto"`` — probe the toolchain and prefer pallas when it can
-  actually run fused (pallas importable, jax new enough to trace
-  ``pallas_call`` under ``shard_map``, real TPU present); fall back to
-  jnp otherwise.  Interpret-mode pallas on CPU is a correctness tier,
+* ``"auto"`` — ``pallas`` on a TPU for the eligible engine, ``jnp``
+  everywhere else.  Interpret-mode pallas on CPU is a correctness tier,
   not a perf tier, so ``auto`` never selects it — tests force
-  ``"pallas"`` explicitly to exercise it.
+  ``"pallas"`` explicitly to exercise it.  On a TPU a pallas that does
+  not import is an error, never a silent drop to ``jnp``.
 
-Both backends are bit-exact: the kernels evaluate the very same
-elementwise expressions, in the same association order, as the jnp
-path (``tests/test_kernels.py`` + the distributed parity suite in
-``tests/test_fused.py`` hold them to ``array_equal``).  The backend is
-therefore a pure execution detail — it never enters the checkpoint
-fingerprint and resume across backends is exact.
+The kernels evaluate the same elementwise expressions, in the same
+association order, as the jnp path: wire payloads are bit-identical
+across backends, and the EF iterates agree to FMA-contraction rounding
+(``tests/test_kernels.py`` + the distributed parity suite in
+``tests/test_fused.py``).  The backend is therefore a pure execution
+detail — it never enters the checkpoint fingerprint and resume across
+backends is exact.
 
-Module level stays jax-free on purpose: the CLI's fail-fast matrix
-imports :func:`jax_version_tuple` before jax (and before XLA_FLAGS are
-frozen) to reject ``--kernel-backend pallas`` on an old toolchain with
-``SystemExit(2)``.
+Module level stays jax-free; jax is imported only where a backend is
+resolved or a kernel runs.
 """
 from __future__ import annotations
-
-import dataclasses
-import functools
 
 #: Recognised values for ``ChocoConfig.kernel_backend`` / ``--kernel-backend``.
 BACKENDS = ("auto", "pallas", "jnp")
 
-#: Oldest jax able to trace ``pallas_call`` under ``shard_map`` at all
-#: (via ``check_rep=False`` — see :func:`shard_map_check_rep`).  Older
-#: toolchains reject pallas pre-jax in the CLI.
-MIN_JAX_FOR_PALLAS = (0, 4, 30)
 
-
-def jax_version_tuple() -> tuple:
-    """The installed jax version as an int 3-tuple, WITHOUT importing jax.
-
-    Read from package metadata so the CLI can gate ``--kernel-backend
-    pallas`` before the first jax import (pre-XLA_FLAGS, pre-device
-    init).  Returns ``(0, 0, 0)`` when jax is not installed.
-    """
-    from importlib.metadata import PackageNotFoundError, version
-    try:
-        raw = version("jax")
-    except PackageNotFoundError:
-        return (0, 0, 0)
-    parts = []
-    for tok in raw.split(".")[:3]:
-        digits = ""
-        for ch in tok:
-            if not ch.isdigit():
-                break
-            digits += ch
-        parts.append(int(digits or 0))
-    while len(parts) < 3:
-        parts.append(0)
-    return tuple(parts)
-
-
-def toolchain_supports_pallas() -> bool:
-    """Whether this jax is new enough for the pallas backend (metadata only)."""
-    return jax_version_tuple() >= MIN_JAX_FOR_PALLAS
-
-
-@dataclasses.dataclass(frozen=True)
-class Toolchain:
-    """Result of the build-time capability probe (:func:`probe_toolchain`)."""
-
-    #: installed jax version (from package metadata)
-    jax_version: tuple
-    #: ``jax.experimental.pallas`` imports on this toolchain
-    pallas_imports: bool
-    #: ``pallas_call`` traces under ``shard_map`` with the default
-    #: ``check_rep=True`` (jax 0.4.x has no replication rule for it, so
-    #: this is False there and the engine passes ``check_rep=False``)
-    shard_map_check_rep: bool
-    #: no TPU attached — kernels must run in interpret mode
-    interpret: bool
-
-
-@functools.lru_cache(maxsize=1)
-def probe_toolchain() -> Toolchain:
-    """Probe, once per process, what the pallas backend may rely on.
-
-    Imports jax (call only from exchange-build time or later, never at
-    CLI validation time — that is what :func:`jax_version_tuple` is
-    for).  The ``shard_map`` probe traces a trivial ``pallas_call``
-    through a 1-device ``shard_map`` abstractly (``eval_shape``, no
-    device computation) to learn whether the default replication check
-    accepts it.
-    """
-    import jax
-    ver = jax_version_tuple()
+def _require_pallas(why: str) -> None:
     try:
         from jax.experimental import pallas  # noqa: F401
-        pallas_imports = True
-    except Exception:
-        pallas_imports = False
-    interpret = jax.default_backend() != "tpu"
-    check_rep = _probe_shard_map_check_rep() if pallas_imports else False
-    return Toolchain(jax_version=ver, pallas_imports=pallas_imports,
-                     shard_map_check_rep=check_rep, interpret=interpret)
-
-
-def _probe_shard_map_check_rep() -> bool:
-    """True iff ``pallas_call`` traces under ``shard_map(check_rep=True)``."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.experimental import pallas as pl
-    from jax.sharding import Mesh, PartitionSpec as P
-    if hasattr(jax, "shard_map"):
-        smap = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as smap
-
-    def kern(x_ref, o_ref):
-        o_ref[...] = x_ref[...] * 2.0
-
-    def local(x):
-        return pl.pallas_call(
-            kern, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-            interpret=True)(x)
-
-    mesh = Mesh(np.asarray(jax.devices()[:1]), ("_probe",))
-    fn = smap(local, mesh=mesh, in_specs=P(), out_specs=P())
-    try:
-        jax.eval_shape(fn, jax.ShapeDtypeStruct((8, 128), jnp.float32))
-        return True
-    except Exception:
-        return False
-
-
-def shard_map_check_rep(backend: str) -> bool:
-    """The ``check_rep`` flag the engine's ``shard_map`` wrapper needs.
-
-    The jnp backend keeps the default (True).  The pallas backend keeps
-    it only when the toolchain has a replication rule for
-    ``pallas_call``; on jax 0.4.x it does not, and ``check_rep=False``
-    is the documented workaround (it only disables the replication
-    *check* — numerics are unchanged).
-    """
-    if backend != "pallas":
-        return True
-    return probe_toolchain().shard_map_check_rep
+    except ImportError as e:
+        raise RuntimeError(
+            f"{why}, but jax.experimental.pallas does not import: {e}") from e
 
 
 def resolve_backend(requested: str, *, engine_eligible: bool = True) -> str:
@@ -163,38 +48,28 @@ def resolve_backend(requested: str, *, engine_eligible: bool = True) -> str:
     ``engine_eligible`` says whether the exchange being built is the
     packed choco engine the fused kernels are wired into (packed
     buckets, no topology process).  Forcing ``"pallas"`` on an
-    ineligible engine or an incapable toolchain raises; ``"auto"``
-    degrades to ``"jnp"`` silently (including on CPU, where pallas
-    would run interpreted — a debug tier, not a perf win).
+    ineligible engine raises; ``"auto"`` on an ineligible engine or off
+    a TPU is ``"jnp"`` (pallas would run interpreted there — a debug
+    tier, not a perf win).
     """
     if requested not in BACKENDS:
         raise ValueError(
             f"unknown kernel backend {requested!r}; expected one of {BACKENDS}")
     if requested == "jnp":
         return "jnp"
-    tc = probe_toolchain()
     if requested == "pallas":
-        if not tc.pallas_imports:
-            raise RuntimeError(
-                "kernel_backend='pallas' requested but jax.experimental.pallas "
-                "does not import on this toolchain")
-        if jax_version_tuple() < MIN_JAX_FOR_PALLAS:
-            raise RuntimeError(
-                "kernel_backend='pallas' needs jax >= "
-                + ".".join(map(str, MIN_JAX_FOR_PALLAS))
-                + " (no shard_map-compatible pallas_call before that); found "
-                + ".".join(map(str, jax_version_tuple())))
         if not engine_eligible:
             raise ValueError(
                 "kernel_backend='pallas' is wired into the packed static "
                 "choco engine only (mode=choco, packed buckets, no topology "
                 "process); use 'auto' or 'jnp' here")
+        _require_pallas("kernel_backend='pallas' requested")
         return "pallas"
-    # auto: pallas only where it is an actual perf win
-    if (engine_eligible and tc.pallas_imports and not tc.interpret
-            and jax_version_tuple() >= MIN_JAX_FOR_PALLAS):
-        return "pallas"
-    return "jnp"
+    import jax
+    if not engine_eligible or jax.default_backend() != "tpu":
+        return "jnp"
+    _require_pallas("kernel_backend='auto' on a TPU resolves to pallas")
+    return "pallas"
 
 
 # ---------------------------------------------------------------------------
@@ -218,9 +93,7 @@ def qsgd_codes(buf32, xi, inv_norm, s: int, *, backend: str):
         from repro.kernels.qsgd import qsgd_quantize_codes
         xt, d = _to_tiles(buf32)
         xit, _ = _to_tiles(xi)
-        tc = probe_toolchain()
-        codes = qsgd_quantize_codes(xt, xit, inv_norm, s,
-                                    interpret=tc.interpret)
+        codes = qsgd_quantize_codes(xt, xit, inv_norm, s)
         return codes.reshape(-1)[:d]
     import jax.numpy as jnp
     level = jnp.floor(jnp.abs(buf32) * inv_norm * s + xi)
@@ -234,7 +107,7 @@ def sign_codes(buf32, *, backend: str):
         from repro.kernels.ops import _to_tiles
         from repro.kernels.qsgd import signnorm_codes
         xt, d = _to_tiles(buf32)
-        codes = signnorm_codes(xt, interpret=probe_toolchain().interpret)
+        codes = signnorm_codes(xt)
         return codes.reshape(-1)[:d]
     import jax.numpy as jnp
     return jnp.sign(buf32).astype(jnp.int8)
@@ -258,8 +131,7 @@ def ef_bucket_update(x_half, x_hat, s, q_self, q_nbr, w_self, w_nbr, gamma,
     if backend == "pallas":
         from repro.kernels.ops import ef_gossip_update_vector
         return ef_gossip_update_vector(
-            x_half, x_hat, s, q_self, q_nbr, w_self, w_nbr, gamma,
-            interpret=probe_toolchain().interpret)
+            x_half, x_hat, s, q_self, q_nbr, w_self, w_nbr, gamma)
     x_hat_n = x_hat + q_self
     s_n = s + (w_self * q_self + w_nbr * q_nbr)
     x_n = x_half + gamma * (s_n - x_hat_n)

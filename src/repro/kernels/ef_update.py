@@ -23,6 +23,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import resolve_interpret
 
 LANES = 128
 
@@ -42,8 +45,11 @@ def _ef_kernel(xh_ref, xhat_ref, s_ref, qs_ref, qn_ref, coef_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
 def ef_gossip_update(x_half, x_hat, s, q_self, q_nbr, w_self, w_nbr, gamma,
-                     *, interpret: bool = True, block_rows: int = 256):
-    """All tensors (R, 128) f32.  Returns (x, x_hat, s)."""
+                     *, interpret=None, block_rows: int = 256):
+    """All tensors (R, 128) f32.  Returns (x, x_hat, s).
+
+    The three scalar coefficients ride in SMEM: the TPU lowering only
+    loads from VMEM/SMEM refs, never from an ``ANY`` (HBM) ref."""
     R, C = x_half.shape
     assert C == LANES and R % block_rows == 0, (R, C)
     grid = (R // block_rows,)
@@ -52,8 +58,12 @@ def ef_gossip_update(x_half, x_hat, s, q_self, q_nbr, w_self, w_nbr, gamma,
     return pl.pallas_call(
         _ef_kernel,
         grid=grid,
-        in_specs=[bs, bs, bs, bs, bs, pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[bs, bs, bs, bs, bs,
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[bs, bs, bs],
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.float32)] * 3,
-        interpret=interpret,
+        # in place: (x_half, x_hat, s) -> (x, x_hat, s); every tile is read
+        # before it is written, so the state needs no second HBM copy
+        input_output_aliases={0: 0, 1: 1, 2: 2},
+        interpret=resolve_interpret(interpret),
     )(x_half, x_hat, s, q_self, q_nbr, coef)

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -64,7 +66,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, causal: bool, softcap,
                                              "block_k", "interpret"))
 def flash_attention_single(q, k, v, *, causal: bool = True, softcap=None,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret=None):
     """q, k, v: (S, Dh) for one (batch, head).  Returns (S, Dh)."""
     S, Dh = q.shape
     block_q = min(block_q, S)
@@ -84,7 +86,7 @@ def flash_attention_single(q, k, v, *, causal: bool = True, softcap=None,
         ],
         out_specs=pl.BlockSpec((block_q, Dh), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((S, Dh), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
 
 
@@ -92,7 +94,7 @@ def flash_attention_single(q, k, v, *, causal: bool = True, softcap=None,
                                              "block_q", "block_k"))
 def flash_attention(q, k, v, *, causal: bool = True, softcap=None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret=None):
     """q: (B, S, H, Dh); k, v: (B, S, KV, Dh) (GQA: H % KV == 0).
     Returns (B, S, H, Dh)."""
     B, S, H, Dh = q.shape

@@ -26,7 +26,7 @@ def _from_tiles(t, d):
 
 
 @functools.partial(jax.jit, static_argnames=("s", "interpret"))
-def qsgd_compress_vector(x, xi, s: int, *, interpret: bool = True):
+def qsgd_compress_vector(x, xi, s: int, *, interpret=None):
     """Flat qsgd: x, xi (d,) -> (codes int8/int16 (d,), scale)."""
     xt, d = _to_tiles(x)
     xit, _ = _to_tiles(xi)
@@ -35,14 +35,14 @@ def qsgd_compress_vector(x, xi, s: int, *, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def qsgd_decompress_vector(codes, scale, *, interpret: bool = True):
+def qsgd_decompress_vector(codes, scale, *, interpret=None):
     """Flat qsgd dequantize: codes (d,), scale scalar -> f32 (d,)."""
     ct, d = _to_tiles(codes)
     return _from_tiles(qsgd_dequantize(ct, scale, interpret=interpret), d)
 
 
 @functools.partial(jax.jit, static_argnames=("k_per_block", "interpret"))
-def block_topk_compress_vector(x, k_per_block: int, *, interpret: bool = True):
+def block_topk_compress_vector(x, k_per_block: int, *, interpret=None):
     """Flat block-top-k: select ~k_per_block per 128-lane row.
     Returns the masked dense q (same shape as x)."""
     xt, d = _to_tiles(x)
@@ -74,7 +74,7 @@ def block_topk_select(x, k_per_block: int, *, block: int = 128):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ef_gossip_update_vector(x_half, x_hat, s, q_self, q_nbr,
-                            w_self, w_nbr, gamma, *, interpret: bool = True):
+                            w_self, w_nbr, gamma, *, interpret=None):
     """Flat fused CHOCO update; all args (d,) f32."""
     tiles = [_to_tiles(a, rows_multiple=256)[0]
              for a in (x_half, x_hat, s, q_self, q_nbr)]

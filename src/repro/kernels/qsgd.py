@@ -21,16 +21,19 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import resolve_interpret
 
 BLOCK_ROWS = 8
 LANES = 128
 
 
-def _quant_kernel(x_ref, xi_ref, inv_norm_ref, s_ref, out_ref, *, ctype):
+def _quant_kernel(x_ref, xi_ref, coef_ref, out_ref, *, ctype):
     x = x_ref[...]
     xi = xi_ref[...]
-    inv_norm = inv_norm_ref[0]
-    s = s_ref[0]
+    inv_norm = coef_ref[0]
+    s = coef_ref[1]
     level = jnp.floor(jnp.abs(x) * inv_norm * s + xi)
     out_ref[...] = (jnp.sign(x) * level).astype(ctype)
 
@@ -49,7 +52,7 @@ def code_dtype(s: int):
 
 
 @functools.partial(jax.jit, static_argnames=("s", "interpret", "block_rows"))
-def qsgd_quantize_codes(x, xi, inv_norm, s: int, *, interpret: bool = True,
+def qsgd_quantize_codes(x, xi, inv_norm, s: int, *, interpret=None,
                         block_rows: int = BLOCK_ROWS):
     """Fused quantize pass: the elementwise half of qsgd, codes only.
 
@@ -67,18 +70,19 @@ def qsgd_quantize_codes(x, xi, inv_norm, s: int, *, interpret: bool = True,
         in_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),     # scalars broadcast to every tile
-            pl.BlockSpec(memory_space=pl.ANY),
+            # (inv_norm, s) broadcast to every tile, from SMEM: the TPU
+            # lowering only loads from VMEM/SMEM refs, never from ANY (HBM)
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, LANES), ctype),
-        interpret=interpret,
-    )(x, xi, jnp.stack([jnp.asarray(inv_norm, jnp.float32)]),
-      jnp.full((1,), float(s), jnp.float32))
+        interpret=resolve_interpret(interpret),
+    )(x, xi, jnp.stack([jnp.asarray(inv_norm, jnp.float32),
+                        jnp.float32(s)]))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def signnorm_codes(x, *, interpret: bool = True,
+def signnorm_codes(x, *, interpret=None,
                    block_rows: int = BLOCK_ROWS):
     """SignNorm wire codes: x (R, 128) f32 tiles -> int8 sign(x)."""
     R, C = x.shape
@@ -89,12 +93,12 @@ def signnorm_codes(x, *, interpret: bool = True,
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, LANES), jnp.int8),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)
 
 
 @functools.partial(jax.jit, static_argnames=("s", "interpret", "block_rows"))
-def qsgd_quantize(x, xi, s: int, *, interpret: bool = True,
+def qsgd_quantize(x, xi, s: int, *, interpret=None,
                   block_rows: int = BLOCK_ROWS):
     """x, xi: (R, 128) f32 tiles (R % block_rows == 0).
     Returns (codes int8/int16 (R,128), scale f32 scalar)."""
@@ -110,7 +114,7 @@ def qsgd_quantize(x, xi, s: int, *, interpret: bool = True,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def qsgd_dequantize(codes, scale, *, interpret: bool = True,
+def qsgd_dequantize(codes, scale, *, interpret=None,
                     block_rows: int = BLOCK_ROWS):
     """codes (R, 128) int8/int16, scale f32 scalar -> f32 (R, 128)."""
     R, C = codes.shape
@@ -119,8 +123,8 @@ def qsgd_dequantize(codes, scale, *, interpret: bool = True,
         _dequant_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, LANES), jnp.float32),
-        interpret=interpret,
-    )(codes, jnp.stack([scale]))
+        interpret=resolve_interpret(interpret),
+    )(codes, jnp.stack([jnp.asarray(scale, jnp.float32)]))

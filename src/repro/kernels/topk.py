@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
+
 LANES = 128
 N_ITER = 24
 
@@ -41,7 +43,7 @@ def _block_topk_kernel(x_ref, k_ref, mask_ref, thresh_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret", "block_rows"))
-def block_topk_mask(x, k: int, *, interpret: bool = True, block_rows: int = 8):
+def block_topk_mask(x, k: int, *, interpret=None, block_rows: int = 8):
     """x: (R, C) with C a multiple of 128.  Per-row top-k mask.
     Returns (mask (R,C) f32, thresholds (R,) f32)."""
     R, C = x.shape
@@ -56,6 +58,6 @@ def block_topk_mask(x, k: int, *, interpret: bool = True, block_rows: int = 8):
                    pl.BlockSpec((block_rows,), lambda i: (i,))],
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.float32),
                    jax.ShapeDtypeStruct((R,), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, jnp.full((1,), k, jnp.int32))
     return mask, thresh

@@ -1,5 +1,6 @@
 """jax-free process-environment helpers (safe to import before XLA_FLAGS
-is frozen by the first jax import)."""
+is frozen by the first jax import): simulated host devices and the home of
+the persistent compile cache."""
 from __future__ import annotations
 
 import os
@@ -19,3 +20,28 @@ def simulate_host_devices(n: int) -> str:
     keep.append(f"{_DEVICE_COUNT_FLAG}={int(n)}")
     os.environ["XLA_FLAGS"] = " ".join(keep)
     return os.environ["XLA_FLAGS"]
+
+
+#: where the compile cache lives when ``JAX_COMPILATION_CACHE_DIR`` is unset:
+#: one fixed directory inside the checkout (gitignored), so every run of
+#: this checkout finds what an earlier run compiled
+DEFAULT_COMPILE_CACHE = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", ".jax_cache"))
+
+
+def place_compile_cache():
+    """Point JAX's persistent compilation cache at its one home; call before
+    the first compile.  With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already
+    reads it and nothing is changed.  Otherwise, on a TPU, the cache goes
+    to :data:`DEFAULT_COMPILE_CACHE`; off the chip nothing is placed (CPU
+    compiles are cheap, and XLA:CPU's cached executables are tied to the
+    compiling host's instruction set).  Returns the directory in use, or
+    None."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    if jax.default_backend() != "tpu":
+        return None
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE)
+    return DEFAULT_COMPILE_CACHE

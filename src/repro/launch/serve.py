@@ -1,4 +1,4 @@
-"""Serving launcher: batched prefill + decode loop against the production
+"""Serving launcher: batched prefill + decode loop against the default
 mesh (or a simulated CPU mesh).
 
     python -m repro.launch.serve --arch gemma2-9b --smoke \
@@ -71,7 +71,7 @@ def main(argv=None):
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs.base import get_config
     from repro.models import build_model
-    from repro.launch.mesh import make_production_mesh, make_mesh
+    from repro.launch.mesh import make_default_mesh, make_mesh
     from repro.launch.sharding import param_pspecs, cache_pspecs
 
     if args.mesh:
@@ -79,7 +79,7 @@ def main(argv=None):
         axes = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
         mesh = make_mesh(dims, axes)
     else:
-        mesh = make_production_mesh()
+        mesh = make_default_mesh()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.family == "audio":

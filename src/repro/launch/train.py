@@ -1,11 +1,15 @@
 """Production training launcher.
 
-On a real TPU slice this runs under `python -m repro.launch.train` on every
-host (jax.distributed initialises from the TPU environment); on CPU it
-simulates the mesh with host devices for integration testing.
+One process drives every chip of a single host (1 or 4 TPU chips); there
+is no ``jax.distributed`` initialisation, and a single host needs none.
+Without ``--mesh`` every visible device is one gossip node.  On CPU it
+simulates the mesh with host devices for integration testing.  A
+published config that does not fit the chip is cut in depth only
+(``--n-layers``); widths are never touched.  At published widths the
+default ``--lr 0.1`` diverges; 0.01 trains:
 
-    python -m repro.launch.train --arch qwen3-1.7b --shape train_4k \
-        --mode choco --compressor top_k --fraction 0.01 --steps 100
+    python -m repro.launch.train --arch qwen3-1.7b --n-layers 4 \
+        --mode choco --compressor top_k --fraction 0.01 --lr 0.01 --steps 100
 
 Checkpoints are sharded directories (manifest.json + per-host shard files;
 see checkpoint/checkpointing.py).  ``--steps`` is the TOTAL step budget:
@@ -20,13 +24,14 @@ x_hat/s re-zeroed + consensus warmup — checkpoint/elastic.py):
         --resume ckpts/step60 --checkpoint-dir ckpts --checkpoint-every 20
 """
 import argparse
+import dataclasses
 import os
 import sys
 
 # jax-free imports: safe before XLA_FLAGS is frozen by the first jax import
-from repro.configs.base import (parse_delay_probs, parse_straggler_edges,
-                                parse_topology)
-from repro.launch.env import simulate_host_devices
+from repro.configs.base import (get_config, parse_delay_probs,
+                                parse_straggler_edges, parse_topology)
+from repro.launch.env import place_compile_cache, simulate_host_devices
 from repro.obs.sinks import (DivergenceMonitor, JsonlSink, MetricLog,
                              StdoutSink)
 from repro.obs.timers import StepTimer
@@ -72,6 +77,10 @@ def main(argv=None):
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced config (CPU-sized)")
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="cut the published config to its first K layers "
+                         "(depth only, every width unchanged) so it fits "
+                         "the chips at hand; not with --smoke")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--seq-len", type=int, default=None)
     ap.add_argument("--batch-per-node", type=int, default=None)
@@ -141,9 +150,9 @@ def main(argv=None):
     ap.add_argument("--kernel-backend", default="auto",
                     choices=["auto", "pallas", "jnp"],
                     help="kernel backend for the gossip hot path "
-                         "(kernels/dispatch.py): 'auto' probes the "
-                         "toolchain and uses the fused Pallas kernels when "
-                         "they run compiled (TPU), 'pallas'/'jnp' force; "
+                         "(kernels/dispatch.py): 'auto' uses the fused "
+                         "Pallas kernels on a TPU and jnp elsewhere, "
+                         "'pallas'/'jnp' force; "
                          "pallas requires --mode choco with the packed "
                          "engine and no --topology-process")
     ap.add_argument("--exact-small-leaves", action="store_true",
@@ -173,7 +182,8 @@ def main(argv=None):
     ap.add_argument("--simulate-devices", type=int, default=0,
                     help=">0: simulate N host devices (CPU testing)")
     ap.add_argument("--mesh", default=None,
-                    help="e.g. 4x2 => (data=4, model=2); default: production")
+                    help="e.g. 4x2 => (data=4, model=2); default: every "
+                         "visible device is one node, (data=N, model=1)")
     ap.add_argument("--metrics-dir", default=None,
                     help="write a structured JSONL run log (metrics.jsonl: "
                          "header record + registry-validated metric records, "
@@ -324,14 +334,6 @@ def main(argv=None):
                      f"engines only; --topology-process "
                      f"{args.topology_process} uses the replica/async "
                      f"engines, which stay jnp")
-        # jax-free version gate (kernels/dispatch.py reads package metadata)
-        from repro.kernels.dispatch import (MIN_JAX_FOR_PALLAS,
-                                            jax_version_tuple)
-        if jax_version_tuple() < MIN_JAX_FOR_PALLAS:
-            ap.error(f"--kernel-backend pallas needs jax >= "
-                     f"{'.'.join(map(str, MIN_JAX_FOR_PALLAS))} "
-                     f"(found {'.'.join(map(str, jax_version_tuple()))}); "
-                     f"use --kernel-backend auto or jnp")
     if args.keep_checkpoints is not None:
         if args.keep_checkpoints < 1:
             ap.error(f"--keep-checkpoints must be >= 1, got "
@@ -350,6 +352,18 @@ def main(argv=None):
         if args.profile_steps < 1:
             ap.error(f"--profile-steps must be >= 1, got "
                      f"{args.profile_steps}")
+    # configs/ is jax-free: the depth cut is validated before device init
+    cfg = get_config(args.arch, smoke=args.smoke)
+    reduced = None
+    if args.n_layers is not None:
+        if args.smoke:
+            ap.error("--n-layers cuts a published config; --smoke already "
+                     "selects the reduced CPU config — drop one of the two")
+        if not 1 <= args.n_layers <= cfg.n_layers:
+            ap.error(f"--n-layers must be in [1, {cfg.n_layers}] (the "
+                     f"published depth of {cfg.name}), got {args.n_layers}")
+        reduced = f"n_layers {cfg.n_layers}->{args.n_layers}"
+        cfg = dataclasses.replace(cfg, n_layers=args.n_layers)
 
     if args.simulate_devices:
         simulate_host_devices(args.simulate_devices)
@@ -362,35 +376,28 @@ def main(argv=None):
 
     import jax
     import jax.numpy as jnp
-    from repro.configs.base import get_config, ChocoConfig
+    from repro.configs.base import ChocoConfig
     from repro.models import build_model
     from repro.models.transformer import count_params
     from repro.train.trainer import DecentralizedTrainer
     from repro.optim import make_optimizer, cosine_schedule
     from repro.data.synthetic import make_lm_batch_fn
-    from repro.launch.mesh import make_production_mesh, make_mesh, gossip_axis_for
+    from repro.launch.mesh import make_default_mesh, make_mesh, gossip_axis_for
     from repro.checkpoint.checkpointing import restore_pytree
     from repro.checkpoint.manifest import (is_sharded_checkpoint,
                                            read_manifest)
 
+    place_compile_cache()
     if args.mesh:
         dims = tuple(int(x) for x in args.mesh.split("x"))
         axes = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
         mesh = make_mesh(dims, axes)
     else:
-        mesh = make_production_mesh()
+        mesh = make_default_mesh()
     gossip_axis = gossip_axis_for(mesh)
     n_nodes = mesh.shape[gossip_axis]
 
-    cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
-    proc_info = ("" if args.topology_process == "none" else
-                 f" process={args.topology_process}")
-    proc_info += " pipelined" if args.pipeline_gossip else ""
-    mlog.log(f"[train] arch={cfg.name} params={count_params(cfg)/1e6:.1f}M "
-             f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
-             f"nodes={n_nodes} mode={args.mode} topology={args.topology} "
-             f"gossip_steps={args.gossip_steps}{proc_info}")
 
     if args.compressor == "qsgd":
         comp_kwargs = (("s", args.qsgd_s),)
@@ -425,6 +432,15 @@ def main(argv=None):
         lr_fn=cosine_schedule(args.lr, warmup=min(100, args.steps // 10 + 1),
                               total=args.steps),
         mode=args.mode)
+    proc_info = ("" if args.topology_process == "none" else
+                 f" process={args.topology_process}")
+    proc_info += " pipelined" if args.pipeline_gossip else ""
+    proc_info += f" reduced: {reduced}" if reduced else ""
+    mlog.log(f"[train] arch={cfg.name} params={count_params(cfg)/1e6:.1f}M "
+             f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
+             f"nodes={n_nodes} mode={args.mode} topology={args.topology} "
+             f"gossip_steps={args.gossip_steps} "
+             f"kernel_backend={trainer.kernel_backend}{proc_info}")
 
     def budget_check(resumed):
         if resumed >= args.steps:
@@ -485,6 +501,8 @@ def main(argv=None):
     mlog.header(arch=cfg.name, mode=args.mode, topology=args.topology,
                 fingerprint=trainer.fingerprint(),
                 jax_version=jax.__version__,
+                kernel_backend=trainer.kernel_backend, reduced=reduced,
+                node_devices=trainer.node_devices(state),
                 mesh=dict(zip(mesh.axis_names, mesh.devices.shape)),
                 gamma=buckets["gamma"], buckets=buckets["buckets"],
                 wire_bytes_round=buckets["wire_bytes_round"])

@@ -276,9 +276,9 @@ def init_embed(key, cfg: ModelConfig, dtype):
 
 
 def embed_tokens(p, tokens, cfg: ModelConfig):
-    """Token lookup (gemma-style sqrt(D) scaling when embeddings tie)."""
+    """Token lookup (gemma-style sqrt(D) scaling when ``cfg.scale_embed``)."""
     x = jnp.take(p["tok"], tokens, axis=0)
-    if cfg.tie_embeddings:              # gemma-style scaled embedding
+    if cfg.scale_embed:
         x = x * math.sqrt(cfg.d_model)
     return x
 
@@ -321,5 +321,7 @@ def chunked_lm_loss(p, h, labels, cfg: ModelConfig, valid=None):
         nll = -jnp.take_along_axis(logp, ll[..., None], axis=-1)[..., 0]
         return jnp.sum(nll * vv), jnp.sum(vv)
 
-    sums, counts = jax.lax.map(chunk_loss, (hc, lc, vc))
+    # rematerialised: without it the map saves every chunk's f32 log-softmax
+    # for the backward pass, i.e. the full (B, S, V) logits after all
+    sums, counts = jax.lax.map(jax.checkpoint(chunk_loss), (hc, lc, vc))
     return jnp.sum(sums) / jnp.maximum(jnp.sum(counts), 1.0)

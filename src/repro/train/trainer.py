@@ -195,6 +195,14 @@ class DecentralizedTrainer:
                     "straggler edges model per-edge DELAYS — they require "
                     "topology_process='staleness', got no topology process")
             self.process = None
+        # the kernel backend the exchange runs, resolved once from the
+        # platform: fused Pallas kernels on a TPU for the packed static
+        # choco engine, jnp elsewhere (kernels/dispatch.py)
+        from repro.kernels.dispatch import resolve_backend
+        self.kernel_backend = resolve_backend(
+            self.choco.kernel_backend,
+            engine_eligible=(self.mode == "choco" and self.choco.packed_gossip
+                             and self.process is None))
         # pipelined engine (comm/pipelined.py): the exchange is issued on
         # the PRE-gradient iterate and its payload lands in the NEXT step's
         # update — validity requires the compressed-increment recursion
@@ -375,6 +383,20 @@ class DecentralizedTrainer:
         shape = self.state_shape(key)
         return jax.jit(self._init_state_fn(),
                        out_shardings=self.state_shardings(shape))(key)
+
+    def node_devices(self, state: TrainState) -> list:
+        """Device ids holding each node's shard of the parameters, indexed
+        by node: one entry per node, read from the arrays' shardings (no
+        device transfer).  Distinct single ids mean one node per device."""
+        held = [set() for _ in range(self.n_nodes)]
+        for leaf in jax.tree.leaves(state.params):
+            for dev, idx in leaf.sharding.devices_indices_map(
+                    leaf.shape).items():
+                start = idx[0].start or 0
+                stop = self.n_nodes if idx[0].stop is None else idx[0].stop
+                for node in range(start, stop):
+                    held[node].add(dev.id)
+        return [sorted(h) for h in held]
 
     # -- checkpointing ---------------------------------------------------------
 
@@ -674,7 +696,7 @@ class DecentralizedTrainer:
             pipelined=self.choco.pipeline_gossip,
             weight_specs=(P(self.gossip_axis, None)
                           if self.mode == "pushsum" else None),
-            kernel_backend=self.choco.kernel_backend)
+            kernel_backend=self.kernel_backend)
 
     # -- jit with shardings -----------------------------------------------------
 

@@ -24,6 +24,7 @@ from repro.comm.schedule import compile_schedule
 from repro.comm.async_gossip import StalenessProcess
 from repro.comm.stochastic import (LinkFailureProcess, choco_process_round,
                                    init_process_state, make_topology_process)
+from repro.launch.mesh import make_mesh
 
 from optional_hypothesis import HAVE_HYPOTHESIS, given, settings, st
 
@@ -398,7 +399,7 @@ class TestFailFast:
         from repro.models import build_model
         from repro.optim import constant_schedule, sgd
         from repro.train.trainer import DecentralizedTrainer
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         cfg = get_config("qwen3-1.7b", smoke=True)
         mode = kw.pop("mode", "choco")
         return DecentralizedTrainer(
@@ -415,7 +416,7 @@ class TestFailFast:
         """make_gossip_exchange itself guards the plain engine (library
         users bypassing the trainer hit the same wall)."""
         from repro.comm.gossip import make_gossip_exchange
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with pytest.raises(ValueError, match="choco engine"):
             make_gossip_exchange(mode="plain", mesh=mesh, state_specs=None,
                                  axis="data", process=_proc("ring", n=8))
@@ -487,7 +488,7 @@ def test_distributed_async_engine_matches_simulator(topology, tau):
         topo = make_topology("{topology}", n)
         sched = compile_schedule(topo)
         proc = StalenessProcess(sched, max_staleness=tau)
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         comp = TopK(k=9)            # deterministic: no RNG divergence
         gamma = 0.3
         x0 = jax.random.normal(jax.random.PRNGKey(0), (n, d))
@@ -536,7 +537,7 @@ def test_async_permute_count_equals_linkfail():
 
         n, d = 8, 256
         sched = compile_schedule(make_topology("ring", n))
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         comp = TopK(k=16)
         x0 = jax.random.normal(jax.random.PRNGKey(0), (n, d))
         R = sched.n_rounds
@@ -577,7 +578,7 @@ def test_trainer_async_e2e_and_staleness_change_restore():
         from repro.optim import sgd, constant_schedule
         from repro.data.synthetic import make_lm_batch_fn
 
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         cfg = get_config("qwen3-1.7b", smoke=True)
         m = build_model(cfg)
         nb = make_lm_batch_fn(cfg, 32, 2, 8)

@@ -17,6 +17,7 @@ from repro.checkpoint.checkpointing import (restore_pytree, restore_sharded,
                                             load_metadata)
 from repro.checkpoint.elastic import (consensus_warmup_rounds, elastic_ratio,
                                       remap_rows, source_rows)
+from repro.launch.mesh import make_mesh
 from repro.checkpoint.manifest import (ElasticRestoreError, ManifestError,
                                        ShardCoverageError, TreeMismatchError,
                                        is_sharded_checkpoint, read_manifest)
@@ -112,7 +113,7 @@ def test_sharded_restore_under_shardings(tmp_path):
     degenerate 1-device mesh here; real 8-device placement is covered by the
     distributed suite."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     tree = {"w": jnp.arange(12, dtype=jnp.float32).reshape(1, 12)}
     shardings = {"w": NamedSharding(mesh, P("data", None))}
     d = str(tmp_path / "ck")

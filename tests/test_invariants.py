@@ -163,7 +163,7 @@ def test_registry_matches_live_pipelined_compile():
         from repro.analysis.invariants import CONTEXT_VARS, assert_invariant
 
         n, d = 8, 512
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         x0 = jax.random.normal(jax.random.PRNGKey(0), (n, d))
         z = jnp.zeros_like(x0)
         audits = {}

@@ -11,6 +11,7 @@ from repro.kernels.qsgd import qsgd_quantize, qsgd_dequantize
 from repro.kernels.topk import block_topk_mask
 from repro.kernels.ef_update import ef_gossip_update
 from repro.kernels.flash_attention import flash_attention
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.PRNGKey(0)
 
@@ -280,18 +281,41 @@ def test_resolve_backend_rules():
         kd.resolve_backend("pallas", engine_eligible=False)
     # auto on an ineligible engine silently stays jnp (never raises)
     assert kd.resolve_backend("auto", engine_eligible=False) == "jnp"
-    assert kd.jax_version_tuple() >= (0, 4)
+    assert kd.resolve_backend("pallas") == "pallas"
 
 
 def test_auto_never_picks_interpret_pallas():
     """'auto' selects pallas only where the kernels run compiled; on the
     CPU test toolchain (interpret-only) it must resolve to jnp."""
     from repro.kernels import dispatch as kd
-    tc = kd.probe_toolchain()
-    if tc.interpret:
+    from repro.kernels import resolve_interpret
+    if jax.default_backend() != "tpu":
+        assert resolve_interpret(None) is True
         assert kd.resolve_backend("auto") == "jnp"
     else:
+        assert resolve_interpret(None) is False
         assert kd.resolve_backend("auto") == "pallas"
+
+
+def test_auto_on_tpu_raises_when_pallas_is_missing(monkeypatch):
+    """On a TPU, 'auto' never drops silently to jnp: a Pallas that does
+    not import is an error."""
+    import builtins
+    from repro.kernels import dispatch as kd
+    real_import = builtins.__import__
+
+    def no_pallas(name, globals=None, locals=None, fromlist=(), level=0):
+        if name == "jax.experimental" and fromlist and "pallas" in fromlist:
+            raise ImportError("pallas removed for this test")
+        return real_import(name, globals, locals, fromlist, level)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(builtins, "__import__", no_pallas)
+    with pytest.raises(RuntimeError, match="does not import"):
+        kd.resolve_backend("auto")
+    with pytest.raises(RuntimeError, match="does not import"):
+        kd.resolve_backend("pallas")
+    # an ineligible engine never needs the kernels
+    assert kd.resolve_backend("auto", engine_eligible=False) == "jnp"
 
 
 def test_dispatch_single_node_exchange_backends_agree():
@@ -300,13 +324,11 @@ def test_dispatch_single_node_exchange_backends_agree():
     through the fused bucket-space path.  One round only — later rounds
     quantize the ulp-drifted x, so x_hat stays bit-exact only for the
     round whose input state is shared (the wire witness)."""
-    import numpy as onp
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
     from repro.comm.gossip import make_gossip_exchange
     from repro.core.compression import QSGD
 
-    mesh = Mesh(onp.asarray(jax.devices()[:1]).reshape(1, 1),
-                ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = {"a": jax.random.normal(jax.random.PRNGKey(1), (1, 300)),
               "b": jax.random.normal(jax.random.PRNGKey(2), (1, 4, 128))}
     specs = {"a": P("data", None), "b": P("data", None, "model")}

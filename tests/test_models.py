@@ -115,7 +115,7 @@ def test_full_config_param_counts():
     expect = {"yi-9b": 8.8e9, "qwen3-moe-30b-a3b": 30.5e9,
               "llama4-maverick-400b-a17b": 398e9, "gemma2-9b": 9.2e9,
               "rwkv6-3b": 2.9e9, "llava-next-mistral-7b": 7.3e9,
-              "gemma-7b": 8.5e9}
+              "gemma-7b": 8.5e9, "qwen3-1.7b": 1.7e9}
     from repro.models.transformer import count_params
     for arch, n in expect.items():
         got = count_params(get_config(arch))

@@ -279,6 +279,9 @@ def test_train_launcher_emits_validated_run_log(tmp_path):
         assert h["fingerprint"]["compressor"] == "top_k"
         assert h["gamma"] > 0 and h["wire_bytes_round"] > 0
         assert h["buckets"] and all("omega" in b for b in h["buckets"])
+        assert h["kernel_backend"] == "jnp"     # auto off the chip
+        assert h["reduced"] is None             # --smoke, no depth cut
+        assert h["node_devices"] == [[i] for i in range(8)]
 
         mets = [r for r in recs if r["kind"] == "metrics"]
         compile_recs = [r for r in mets if "train/compile_s" in r]

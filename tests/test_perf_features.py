@@ -25,6 +25,7 @@ def run_sub(body: str, timeout=420):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
         import numpy as np
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=SRC)
     env.pop("XLA_FLAGS", None)
@@ -97,7 +98,7 @@ def test_bf16_ef_state_trainer():
         from repro.optim import sgd, constant_schedule
         from repro.data.synthetic import make_lm_batch_fn
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("yi-9b", smoke=True)
         m = build_model(cfg)
         tr = DecentralizedTrainer(model=m,
@@ -129,7 +130,7 @@ def test_torus_gossip_trainer():
         from repro.optim import sgd, constant_schedule
         from repro.data.synthetic import make_lm_batch_fn
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = get_config("qwen3-1.7b", smoke=True)
         m = build_model(cfg)
         tr = DecentralizedTrainer(model=m,
@@ -157,7 +158,7 @@ def test_exact_small_leaves_ships_dense():
         from jax.sharding import PartitionSpec as P
         from repro.comm.gossip import make_gossip_exchange
         from repro.core import TopK
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         x = {"big": jax.random.normal(jax.random.PRNGKey(0), (4, 4096)),
              "small": jax.random.normal(jax.random.PRNGKey(1), (4, 16))}
         zeros = jax.tree.map(jnp.zeros_like, x)

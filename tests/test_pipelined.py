@@ -30,6 +30,7 @@ def run_sub(body: str, timeout=560):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
         import numpy as np
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=SRC + os.pathsep + ROOT)
     env.pop("XLA_FLAGS", None)
@@ -205,7 +206,7 @@ def test_pipelined_engine_matches_matrix_simulator(packed):
         W = jnp.asarray(topo.W, jnp.float32)
         comp = make_compressor("top_k", fraction=0.25)
         gamma = 0.3
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         key = jax.random.PRNGKey(0)
         x0 = jax.random.normal(jax.random.fold_in(key, 1), (N, D))
 
@@ -258,7 +259,7 @@ def test_per_bucket_gamma_engine_matches_per_bucket_simulator(pipelined):
         comp = TopK(fraction=0.05)          # deterministic
         gs = GammaSpec(delta=topo.delta, beta=topo.beta,
                        omega_scale={0.5 if pipelined else 1.0})
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         k0 = jax.random.PRNGKey(3)
         big = jax.random.normal(jax.random.fold_in(k0, 0), (N, DBIG))
         small = jax.random.normal(jax.random.fold_in(k0, 1), (N, DSMALL))
@@ -325,7 +326,6 @@ def test_fingerprint_compression_change_routes_elastic():
         from repro.models import build_model
         from repro.train.trainer import DecentralizedTrainer
         from repro.optim import make_optimizer, cosine_schedule
-        from repro.launch.mesh import make_mesh
         from repro.checkpoint.manifest import manifest_path
 
         cfg = get_config("qwen3-1.7b", smoke=True)
@@ -390,7 +390,6 @@ def test_pipelined_collective_is_batch_independent():
         from repro.train.trainer import DecentralizedTrainer
         from repro.optim import make_optimizer, cosine_schedule
         from repro.data.synthetic import make_lm_batch_fn
-        from repro.launch.mesh import make_mesh
         from repro.analysis.hlo_audit import collective_dependency_audit
 
         cfg = get_config("qwen3-1.7b", smoke=True)
@@ -437,7 +436,6 @@ def test_pipelined_trainer_end_to_end_converges():
         from repro.train.trainer import DecentralizedTrainer
         from repro.optim import make_optimizer, cosine_schedule
         from repro.data.synthetic import make_lm_batch_fn
-        from repro.launch.mesh import make_mesh
         from repro.core.choco_gossip import GammaSpec
 
         cfg = get_config("qwen3-1.7b", smoke=True)

@@ -178,7 +178,7 @@ def test_distributed_straggler_engine_matches_simulator(probs):
         proc = StalenessProcess(sched, max_staleness=tau,
                                 straggler_edges=((0, 1), (4, 5)),
                                 straggler_delay_probs={sprobs})
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         comp = TopK(k=9)            # deterministic: no RNG divergence
         gamma = 0.3
         x0 = jax.random.normal(jax.random.PRNGKey(0), (n, d))

@@ -25,6 +25,7 @@ from repro.comm.stochastic import (LinkFailureProcess, MatchingProcess,
                                    init_process_state, make_topology_process,
                                    run_choco_gossip_process)
 
+from repro.launch.mesh import make_mesh
 from optional_hypothesis import HAVE_HYPOTHESIS, given, settings, st
 
 TOPOS = ["ring", "hypercube", "star", "chain", "torus", "fully_connected"]
@@ -355,7 +356,7 @@ class TestFailFast:
         from repro.models import build_model
         from repro.optim import constant_schedule, sgd
         from repro.train.trainer import DecentralizedTrainer
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         cfg = get_config("qwen3-1.7b", smoke=True)
         mode = kw.pop("mode", "choco")
         return DecentralizedTrainer(
@@ -440,7 +441,7 @@ def test_distributed_process_engine_matches_simulator(topology, kind):
         topo = make_topology("{topology}", n)
         sched = compile_schedule(topo)
         proc = make_topology_process("{kind}", sched, edge_drop_prob=0.3)
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         comp = TopK(k=9)            # deterministic: no RNG divergence
         gamma = 0.3
         x0 = jax.random.normal(jax.random.PRNGKey(0), (n, d))
@@ -485,7 +486,7 @@ def test_distributed_plain_matching_is_exact_sampled_gossip():
         n, d = 8, 32
         topo = make_topology("hypercube", n)
         proc = MatchingProcess(compile_schedule(topo))
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         x0 = jax.random.normal(jax.random.PRNGKey(0), (n, d))
         Ms = jnp.asarray(proc.branch_matrices())
 
@@ -524,7 +525,7 @@ def test_distributed_matching_single_permute_launch():
         topo = make_topology("fully_connected", n)   # 7 static rounds
         sched = compile_schedule(topo)
         proc = MatchingProcess(sched)
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         ex = make_gossip_exchange(
             mode="choco", mesh=mesh, state_specs=P("data", None),
             axis="data", compressor=TopK(k=16), gamma=0.3, process=proc)
@@ -580,7 +581,7 @@ def test_distributed_pushsum_directed_ring_e2e():
         n, d = 8, 96
         topo = directed_ring(n)
         sched = compile_directed_schedule(topo)
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         comp = TopK(k=24)
         gamma = 0.5
         x0 = jax.random.normal(jax.random.PRNGKey(0), (n, d))
@@ -632,7 +633,7 @@ def test_trainer_process_and_pushsum_e2e():
         from repro.optim import sgd, constant_schedule
         from repro.data.synthetic import make_lm_batch_fn
 
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         cfg = get_config("qwen3-1.7b", smoke=True)
         m = build_model(cfg)
         nb = make_lm_batch_fn(cfg, 32, 2, 8)
@@ -693,7 +694,7 @@ def test_static_paths_unchanged_regression():
         n, d = 8, 64
         topo = make_topology("hypercube", n)
         sched = compile_schedule(topo)
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         comp = TopK(k=16)
         gamma = 0.3
         x0 = jax.random.normal(jax.random.PRNGKey(0), (n, d))
@@ -743,7 +744,7 @@ def test_checkpoint_restore_across_process_change():
         from repro.optim import sgd, constant_schedule
         from repro.data.synthetic import make_lm_batch_fn
 
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         cfg = get_config("qwen3-1.7b", smoke=True)
         m = build_model(cfg)
         nb = make_lm_batch_fn(cfg, 32, 2, 8)
