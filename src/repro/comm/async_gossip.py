@@ -348,6 +348,7 @@ def make_async_choco_fn(*, axes: Tuple[str, ...], sizes: Tuple[int, ...],
     assert gossip_steps >= 1
     from repro.comm.gossip import (_LazyFlatIndex, _ef_send_half,
                                    _make_compress_stage, _pack_align)
+    from repro.comm.stages import stage
     axis_arg = axes[0] if len(axes) == 1 else tuple(axes)
     align = _pack_align(compressor, pack_align)
     rounds = process.schedule.rounds
@@ -375,33 +376,37 @@ def make_async_choco_fn(*, axes: Tuple[str, ...], sizes: Tuple[int, ...],
                 compress_stage, tkey, leaves_x, hat)
             if tau:
                 own_ring = [q_trees] + own_ring[:-1]
-            dvecs = process.round_delays(
-                process.edge_delays(sample_key, t))
+            with stage("mix"):
+                dvecs = process.round_delays(
+                    process.edge_delays(sample_key, t))
             acc = [jnp.zeros((), a.dtype) for a in leaves_x]
             for r in range(R):
-                got = jax.lax.ppermute(payloads, axis_arg,
-                                       list(rounds[r].perm))
-                recv_dense = dense_fn(got)
-                recv_trees = [rd.reshape(sv.shape).astype(sv.dtype)
-                              for sv, rd in zip(S[r], recv_dense)]
-                # the replica ALWAYS integrates (the payload was sent; the
-                # delay gates only which snapshot the update reads below)
-                S[r] = [sv + rt for sv, rt in zip(S[r], recv_trees)]
-                if tau:
-                    rings[r] = [recv_trees] + rings[r][:-1]
-                d = dvecs[r][i]
-                wv = jnp.asarray(process.round_recv[r], jnp.float32)[i]
-                diff = [sr - h for sr, h in zip(S[r], hat)]
-                for j in range(tau):
-                    m = (d > j).astype(jnp.float32)
-                    diff = [df - m * (rr - orr)
-                            for df, rr, orr in zip(diff, rings[r][j],
-                                                   own_ring[j])]
-                acc = [a + wv * df for a, df in zip(acc, diff)]
-            # acc is f32 (strong per-node weights / masks): cast the whole
-            # update back so bf16 params stay bf16
-            leaves_x = [a + (gamma * ac).astype(a.dtype)
-                        for a, ac in zip(leaves_x, acc)]
+                with stage("mix"):
+                    got = jax.lax.ppermute(payloads, axis_arg,
+                                           list(rounds[r].perm))
+                    recv_dense = dense_fn(got)
+                with stage("ef_update"):
+                    recv_trees = [rd.reshape(sv.shape).astype(sv.dtype)
+                                  for sv, rd in zip(S[r], recv_dense)]
+                    # the replica ALWAYS integrates (the payload was sent;
+                    # the delay gates only which snapshot the update reads)
+                    S[r] = [sv + rt for sv, rt in zip(S[r], recv_trees)]
+                    if tau:
+                        rings[r] = [recv_trees] + rings[r][:-1]
+                    d = dvecs[r][i]
+                    wv = jnp.asarray(process.round_recv[r], jnp.float32)[i]
+                    diff = [sr - h for sr, h in zip(S[r], hat)]
+                    for j in range(tau):
+                        m = (d > j).astype(jnp.float32)
+                        diff = [df - m * (rr - orr)
+                                for df, rr, orr in zip(diff, rings[r][j],
+                                                       own_ring[j])]
+                    acc = [a + wv * df for a, df in zip(acc, diff)]
+            with stage("ef_update"):
+                # acc is f32 (strong per-node weights / masks): cast the
+                # whole update back so bf16 params stay bf16
+                leaves_x = [a + (gamma * ac).astype(a.dtype)
+                            for a, ac in zip(leaves_x, acc)]
         u = treedef.unflatten
         new_hat_list = [u(hat)] + [u(tr) for tr in own_ring]
         new_s_list = ([u(S[r]) for r in range(R)]

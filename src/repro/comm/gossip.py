@@ -60,6 +60,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.compression import Compressor
 from repro.comm.schedule import GossipSchedule
+from repro.comm.stages import stage
 
 def _leaf_keys(key, n: int, salt: int):
     return jax.random.split(jax.random.fold_in(key, salt), n)
@@ -151,12 +152,14 @@ def _packed_self_half(compressor, key, leaves_h, leaves_hat, spec,
     ``backend`` is the resolved kernel backend for the quantize math
     (kernels/dispatch.py); both backends produce identical wire bytes."""
     from repro.comm.packing import compress_packed
-    deltas = [(lh.astype(lhat.dtype) - lhat).ravel()
-              for lh, lhat in zip(leaves_h, leaves_hat)]
+    with stage("compress"):
+        deltas = [(lh.astype(lhat.dtype) - lhat).ravel()
+                  for lh, lhat in zip(leaves_h, leaves_hat)]
     payloads, q_leaves = compress_packed(compressor, key, spec, deltas,
                                          backend=backend)
-    new_hat = [lhat + q.reshape(lh.shape).astype(lhat.dtype)
-               for lh, lhat, q in zip(leaves_h, leaves_hat, q_leaves)]
+    with stage("ef_update"):
+        new_hat = [lhat + q.reshape(lh.shape).astype(lhat.dtype)
+                   for lh, lhat, q in zip(leaves_h, leaves_hat, q_leaves)]
     return payloads, q_leaves, new_hat
 
 
@@ -170,17 +173,19 @@ def _per_leaf_self_half(compressor, identity, exact_small_leaves: bool,
     keys = _leaf_keys(tkey, len(leaves_h), 0)
     payloads, dense_fns, new_hat, q_dense = [], [], [], []
     for i, (lh, lhat) in enumerate(zip(leaves_h, leaves_hat)):
-        # compress in the EF-state dtype: bf16 states -> bf16 wire
-        delta = (lh.astype(lhat.dtype) - lhat).ravel()
-        comp_i = (identity if exact_small_leaves
-                  and delta.size <= small_leaf_threshold else compressor)
-        pl, dfn = _compress_leaf(
-            comp_i, keys[i] if comp_i.stochastic else None, delta)
-        payloads.append(pl)
-        dense_fns.append(dfn)
-        qd = dfn(pl)
-        q_dense.append(qd)
-        new_hat.append(lhat + qd.reshape(lh.shape).astype(lhat.dtype))
+        with stage("compress"):
+            # compress in the EF-state dtype: bf16 states -> bf16 wire
+            delta = (lh.astype(lhat.dtype) - lhat).ravel()
+            comp_i = (identity if exact_small_leaves
+                      and delta.size <= small_leaf_threshold else compressor)
+            pl, dfn = _compress_leaf(
+                comp_i, keys[i] if comp_i.stochastic else None, delta)
+            payloads.append(pl)
+            dense_fns.append(dfn)
+            qd = dfn(pl)
+            q_dense.append(qd)
+        with stage("ef_update"):
+            new_hat.append(lhat + qd.reshape(lh.shape).astype(lhat.dtype))
     return payloads, dense_fns, q_dense, new_hat
 
 
@@ -191,11 +196,13 @@ def _choco_leaf_updates(leaves_h, leaves_s, q_leaves, nbr_leaves, new_hat,
     stepsizes resolved by :func:`_resolve_leaf_gammas`)."""
     gammas = _broadcast_gammas(gamma, len(leaves_h))
     new_s, new_x = [], []
-    for lh, ls, qd, nb, nh, g in zip(leaves_h, leaves_s, q_leaves,
-                                     nbr_leaves, new_hat, gammas):
-        sn = ls + (w_self * qd + w_nbr * nb).reshape(lh.shape).astype(ls.dtype)
-        new_s.append(sn)
-        new_x.append(lh + g * (sn - nh).astype(lh.dtype))
+    with stage("ef_update"):
+        for lh, ls, qd, nb, nh, g in zip(leaves_h, leaves_s, q_leaves,
+                                         nbr_leaves, new_hat, gammas):
+            sn = ls + (w_self * qd + w_nbr * nb).reshape(
+                lh.shape).astype(ls.dtype)
+            new_s.append(sn)
+            new_x.append(lh + g * (sn - nh).astype(lh.dtype))
     return new_s, new_x
 
 
@@ -316,6 +323,17 @@ def _neighbor_sum(payloads, groups, axis_arg, dense_fn, flat_idx_fn):
         total = contrib if total is None else [t + c
                                                for t, c in zip(total, contrib)]
     return total, 1.0
+
+
+def _mix(payloads, q_like, groups, axis_arg, dense_fn, flat_idx_fn):
+    """Stage ``mix``: the neighbour aggregate of :func:`_neighbor_sum`, or
+    with no neighbours (n == 1) zeros shaped like the own dense q
+    ``q_like`` and weight 0."""
+    with stage("mix"):
+        if not groups:
+            return [q * 0.0 for q in q_like], 0.0
+        return _neighbor_sum(payloads, groups, axis_arg, dense_fn,
+                             flat_idx_fn)
 
 
 class _LazyFlatIndex:
@@ -509,33 +527,36 @@ def make_choco_schedule_fn(*, axes: Tuple[str, ...], sizes: Tuple[int, ...],
             # there, and the EF update maps (0,0,0,0,0) -> (0,0,0).
             bucket_gammas = _resolve_bucket_gammas(gamma, spec, compressor)
             shapes = [lh.shape for lh in leaves_h]
-            h_bufs = pack_leaves(spec, leaves_h)
-            hat_bufs = pack_leaves(spec, leaves_hat)
-            s_bufs = pack_leaves(spec, leaves_s)
+            with stage("pack"):
+                h_bufs = pack_leaves(spec, leaves_h)
+                hat_bufs = pack_leaves(spec, leaves_hat)
+                s_bufs = pack_leaves(spec, leaves_s)
             for t in range(gossip_steps):
                 sched, groups = compiled[t % len(compiled)]
                 tkey = key if t == 0 else jax.random.fold_in(key, t)
-                d_bufs = [hb - hatb for hb, hatb in zip(h_bufs, hat_bufs)]
-                payloads, q_bufs = compress_bufs(compressor, tkey, spec,
-                                                 d_bufs, backend="pallas")
-                if not groups:                 # n == 1: no neighbours
-                    nbr_bufs, w_nbr = [q * 0.0 for q in q_bufs], 0.0
-                else:
-                    nbr_bufs, w_nbr = _neighbor_sum(
-                        payloads, groups, axis_arg, dense_fn, flat_idx)
-                w_self = _self_weight(sched, flat_idx)
-                for b in range(spec.n_buckets):
-                    h_bufs[b], hat_bufs[b], s_bufs[b] = \
-                        kdispatch.ef_bucket_update(
-                            h_bufs[b], hat_bufs[b], s_bufs[b],
-                            q_bufs[b], nbr_bufs[b], w_self, w_nbr,
-                            bucket_gammas[b], backend="pallas")
-            leaves_h = [f.reshape(sh) for f, sh
-                        in zip(unpack_leaves(spec, h_bufs), shapes)]
-            leaves_hat = [f.reshape(sh) for f, sh
-                          in zip(unpack_leaves(spec, hat_bufs), shapes)]
-            leaves_s = [f.reshape(sh) for f, sh
-                        in zip(unpack_leaves(spec, s_bufs), shapes)]
+                with stage("compress"):
+                    d_bufs = [hb - hatb for hb, hatb
+                              in zip(h_bufs, hat_bufs)]
+                    payloads, q_bufs = compress_bufs(
+                        compressor, tkey, spec, d_bufs, backend="pallas")
+                nbr_bufs, w_nbr = _mix(payloads, q_bufs, groups, axis_arg,
+                                       dense_fn, flat_idx)
+                with stage("mix"):
+                    w_self = _self_weight(sched, flat_idx)
+                with stage("ef_update"):
+                    for b in range(spec.n_buckets):
+                        h_bufs[b], hat_bufs[b], s_bufs[b] = \
+                            kdispatch.ef_bucket_update(
+                                h_bufs[b], hat_bufs[b], s_bufs[b],
+                                q_bufs[b], nbr_bufs[b], w_self, w_nbr,
+                                bucket_gammas[b], backend="pallas")
+            with stage("unpack"):
+                leaves_h = [f.reshape(sh) for f, sh
+                            in zip(unpack_leaves(spec, h_bufs), shapes)]
+                leaves_hat = [f.reshape(sh) for f, sh
+                              in zip(unpack_leaves(spec, hat_bufs), shapes)]
+                leaves_s = [f.reshape(sh) for f, sh
+                            in zip(unpack_leaves(spec, s_bufs), shapes)]
             return (unflatten(leaves_h), unflatten(leaves_hat),
                     unflatten(leaves_s))
 
@@ -546,13 +567,13 @@ def make_choco_schedule_fn(*, axes: Tuple[str, ...], sizes: Tuple[int, ...],
             payloads, q_leaves, new_hat = _packed_self_half(
                 compressor, tkey, leaves_h, leaves_hat, spec,
                 backend=kernel_backend)
-            if not groups:                     # n == 1: no neighbours
-                nbr_leaves, w_nbr = [q * 0.0 for q in q_leaves], 0.0
-            else:
-                nbr_bufs, w_nbr = _neighbor_sum(payloads, groups, axis_arg,
-                                                dense_fn, flat_idx)
-                nbr_leaves = unpack_leaves(spec, nbr_bufs)
-            w_self = _self_weight(sched, flat_idx)
+            nbr_leaves, w_nbr = _mix(payloads, q_leaves, groups, axis_arg,
+                                     dense_fn, flat_idx)
+            if groups:                     # bucket space -> leaves
+                with stage("unpack"):
+                    nbr_leaves = unpack_leaves(spec, nbr_leaves)
+            with stage("mix"):
+                w_self = _self_weight(sched, flat_idx)
             leaves_s, leaves_h = _choco_leaf_updates(
                 leaves_h, leaves_s, q_leaves, nbr_leaves, new_hat,
                 w_self, w_nbr, gammas)
@@ -575,14 +596,12 @@ def make_choco_schedule_fn(*, axes: Tuple[str, ...], sizes: Tuple[int, ...],
             payloads, dense_fns, q_dense, new_hat = _per_leaf_self_half(
                 compressor, identity, exact_small_leaves,
                 small_leaf_threshold, tkey, leaves_h, leaves_hat)
-            if not groups:
-                nbr_sum, w_nbr = [q * 0.0 for q in q_dense], 0.0
-            else:
-                dense_fn = lambda got: [dfn(g) for dfn, g
-                                        in zip(dense_fns, got)]
-                nbr_sum, w_nbr = _neighbor_sum(payloads, groups, axis_arg,
-                                               dense_fn, flat_idx)
-            w_self = _self_weight(sched, flat_idx)
+            dense_fn = lambda got: [dfn(g) for dfn, g
+                                    in zip(dense_fns, got)]
+            nbr_sum, w_nbr = _mix(payloads, q_dense, groups, axis_arg,
+                                  dense_fn, flat_idx)
+            with stage("mix"):
+                w_self = _self_weight(sched, flat_idx)
             leaves_s, leaves_h = _choco_leaf_updates(
                 leaves_h, leaves_s, q_dense, nbr_sum, new_hat,
                 w_self, w_nbr, gamma)
@@ -626,12 +645,14 @@ def _make_compress_stage(compressor: Compressor, *, packed: bool, align: int,
     def per_leaf_stage(tkey, deltas, shapes_like):
         keys = _leaf_keys(tkey, len(deltas), 0)
         payloads, dfns, q_leaves = [], [], []
-        for i, d in enumerate(deltas):
-            pl, dfn = _compress_leaf(
-                compressor, keys[i] if compressor.stochastic else None, d)
-            payloads.append(pl)
-            dfns.append(dfn)
-            q_leaves.append(dfn(pl))
+        with stage("compress"):
+            for i, d in enumerate(deltas):
+                pl, dfn = _compress_leaf(
+                    compressor, keys[i] if compressor.stochastic else None,
+                    d)
+                payloads.append(pl)
+                dfns.append(dfn)
+                q_leaves.append(dfn(pl))
         return payloads, q_leaves, (
             lambda got: [dfn(g) for dfn, g in zip(dfns, got)])
 
@@ -647,12 +668,14 @@ def _ef_send_half(compress_stage, tkey, leaves_x, hat):
     collective's start/done pair separable in the compiled HLO (the
     property the pipelined engine and benchmarks/bench_overlap.py rely on).
     """
-    deltas = [(a.astype(h.dtype) - h).ravel()
-              for a, h in zip(leaves_x, hat)]
+    with stage("compress"):
+        deltas = [(a.astype(h.dtype) - h).ravel()
+                  for a, h in zip(leaves_x, hat)]
     payloads, q_leaves, dense_fn = compress_stage(tkey, deltas, hat)
-    q_trees = [q.reshape(h.shape).astype(h.dtype)
-               for h, q in zip(hat, q_leaves)]
-    new_hat = [h + q for h, q in zip(hat, q_trees)]
+    with stage("ef_update"):
+        q_trees = [q.reshape(h.shape).astype(h.dtype)
+                   for h, q in zip(hat, q_leaves)]
+        new_hat = [h + q for h, q in zip(hat, q_trees)]
     return payloads, q_trees, new_hat, dense_fn
 
 
@@ -730,24 +753,29 @@ def make_process_choco_fn(*, axes: Tuple[str, ...], sizes: Tuple[int, ...],
                 def run(ops):
                     lx, Hs, Ss = ops
                     ref = Hs[r]
-                    deltas = [(a.astype(h.dtype) - h).ravel()
-                              for a, h in zip(lx, ref)]
+                    with stage("compress"):
+                        deltas = [(a.astype(h.dtype) - h).ravel()
+                                  for a, h in zip(lx, ref)]
                     payloads, q_leaves, dense_fn = compress_stage(
                         jax.random.fold_in(tkey, r), deltas, ref)
-                    m_send = send[i]
-                    new_ref = [h + (m_send
-                                    * q.reshape(h.shape)).astype(h.dtype)
-                               for h, q in zip(ref, q_leaves)]
-                    got = jax.lax.ppermute(payloads, axis_arg, perm)
-                    recv_dense = dense_fn(got)
-                    # non-receivers get ppermute zeros: replica unchanged
-                    new_rep = [sv + rd.reshape(sv.shape).astype(sv.dtype)
-                               for sv, rd in zip(Ss[r], recv_dense)]
-                    v = recv[i]
-                    # cast the whole f32-weighted update back: v is a strong
-                    # f32 scalar and would silently upcast bf16 params
-                    new_x = [a + (gamma * v * (sr - hr)).astype(a.dtype)
-                             for a, sr, hr in zip(lx, new_rep, new_ref)]
+                    with stage("ef_update"):
+                        m_send = send[i]
+                        new_ref = [h + (m_send * q.reshape(h.shape)).astype(
+                                       h.dtype)
+                                   for h, q in zip(ref, q_leaves)]
+                    with stage("mix"):
+                        got = jax.lax.ppermute(payloads, axis_arg, perm)
+                        recv_dense = dense_fn(got)
+                    with stage("ef_update"):
+                        # non-receivers get ppermute zeros: replica unchanged
+                        new_rep = [sv + rd.reshape(sv.shape).astype(sv.dtype)
+                                   for sv, rd in zip(Ss[r], recv_dense)]
+                        v = recv[i]
+                        # cast the whole f32-weighted update back: v is a
+                        # strong f32 scalar and would silently upcast bf16
+                        # params
+                        new_x = [a + (gamma * v * (sr - hr)).astype(a.dtype)
+                                 for a, sr, hr in zip(lx, new_rep, new_ref)]
                     Hs2 = [new_ref if rr == r else Hs[rr] for rr in range(R)]
                     Ss2 = [new_rep if rr == r else Ss[rr] for rr in range(R)]
                     return new_x, Hs2, Ss2
@@ -770,34 +798,41 @@ def make_process_choco_fn(*, axes: Tuple[str, ...], sizes: Tuple[int, ...],
         i = flat_idx()
         for t in range(gossip_steps):
             tkey = key if t == 0 else jax.random.fold_in(key, t)
-            deltas = [(a.astype(h.dtype) - h).ravel()
-                      for a, h in zip(leaves_x, leaves_hat)]
+            with stage("compress"):
+                deltas = [(a.astype(h.dtype) - h).ravel()
+                          for a, h in zip(leaves_x, leaves_hat)]
             payloads, q_leaves, dense_fn = compress_stage(tkey, deltas,
                                                           leaves_hat)
-            leaves_hat = [h + q.reshape(h.shape).astype(h.dtype)
-                          for h, q in zip(leaves_hat, q_leaves)]
-            mask = process.edge_mask(sample_key, t)
-            rmasks = process.round_masks(mask)
+            with stage("ef_update"):
+                leaves_hat = [h + q.reshape(h.shape).astype(h.dtype)
+                              for h, q in zip(leaves_hat, q_leaves)]
+            with stage("mix"):
+                mask = process.edge_mask(sample_key, t)
+                rmasks = process.round_masks(mask)
             acc = [jnp.zeros((), a.dtype) for a in leaves_x]
             new_S = []
             for r, rnd in enumerate(rounds):
-                got = jax.lax.ppermute(payloads, axis_arg, list(rnd.perm))
-                recv_dense = dense_fn(got)
-                # the replica ALWAYS integrates (the payload was sent; the
-                # lossy link gates only the mixing weight below) — it must
-                # keep tracking the source's x_hat exactly
-                S_r = [sv + rd.reshape(sv.shape).astype(sv.dtype)
-                       for sv, rd in zip(S[r], recv_dense)]
-                new_S.append(S_r)
-                wv = (jnp.asarray(process.round_recv[r], jnp.float32)
-                      * rmasks[r])[i]
-                acc = [a + wv * (sr - h)
-                       for a, sr, h in zip(acc, S_r, leaves_hat)]
+                with stage("mix"):
+                    got = jax.lax.ppermute(payloads, axis_arg,
+                                           list(rnd.perm))
+                    recv_dense = dense_fn(got)
+                with stage("ef_update"):
+                    # the replica ALWAYS integrates (the payload was sent;
+                    # the lossy link gates only the mixing weight below) —
+                    # it must keep tracking the source's x_hat exactly
+                    S_r = [sv + rd.reshape(sv.shape).astype(sv.dtype)
+                           for sv, rd in zip(S[r], recv_dense)]
+                    new_S.append(S_r)
+                    wv = (jnp.asarray(process.round_recv[r], jnp.float32)
+                          * rmasks[r])[i]
+                    acc = [a + wv * (sr - h)
+                           for a, sr, h in zip(acc, S_r, leaves_hat)]
             S = new_S
-            # acc is f32 (strong per-node mask weights): cast the whole
-            # update back so bf16 params stay bf16
-            leaves_x = [a + (gamma * ac).astype(a.dtype)
-                        for a, ac in zip(leaves_x, acc)]
+            with stage("ef_update"):
+                # acc is f32 (strong per-node mask weights): cast the whole
+                # update back so bf16 params stay bf16
+                leaves_x = [a + (gamma * ac).astype(a.dtype)
+                            for a, ac in zip(leaves_x, acc)]
         u = treedef.unflatten
         return u(leaves_x), u(leaves_hat), [u(sv) for sv in S]
 
@@ -832,18 +867,21 @@ def make_plain_schedule_fn(*, axes: Tuple[str, ...], sizes: Tuple[int, ...],
             if process is None and not groups:
                 continue
             leaves, treedef = jax.tree_util.tree_flatten(x)
-            if process is not None:
-                nbr, w_nbr, w_self = _process_neighbor_sum(
-                    process, leaves, axis_arg, lambda got: got, flat_idx,
-                    sample_key, t)
-            else:
-                nbr, w_nbr = _neighbor_sum(leaves, groups, axis_arg,
-                                           lambda got: got, flat_idx)
-                w_self = _self_weight(sched, flat_idx)
-            # cast back: per-node weights are f32 scalars and would upcast
-            # bf16 params (uniform python-float weights make this a no-op)
-            x = treedef.unflatten([(w_self * a + w_nbr * b).astype(a.dtype)
-                                   for a, b in zip(leaves, nbr)])
+            with stage("mix"):
+                if process is not None:
+                    nbr, w_nbr, w_self = _process_neighbor_sum(
+                        process, leaves, axis_arg, lambda got: got, flat_idx,
+                        sample_key, t)
+                else:
+                    nbr, w_nbr = _neighbor_sum(leaves, groups, axis_arg,
+                                               lambda got: got, flat_idx)
+                    w_self = _self_weight(sched, flat_idx)
+                # cast back: per-node weights are f32 scalars and would
+                # upcast bf16 params (uniform python-float weights make
+                # this a no-op)
+                x = treedef.unflatten(
+                    [(w_self * a + w_nbr * b).astype(a.dtype)
+                     for a, b in zip(leaves, nbr)])
         return x, x_hat, s
 
     return local_fn
@@ -855,7 +893,9 @@ def make_allreduce_fn(*, axes) -> Callable:
 
     def local_fn(key, x_half, x_hat, s):
         del key
-        new_x = jax.tree.map(lambda a: jax.lax.pmean(a, axis_arg), x_half)
+        with stage("mix"):
+            new_x = jax.tree.map(lambda a: jax.lax.pmean(a, axis_arg),
+                                 x_half)
         return new_x, x_hat, s
     return local_fn
 

@@ -46,6 +46,7 @@ from repro.core.compression import (BlockTopK, Compressor, DensePayload,
                                     Identity, PackedQuantPayload,
                                     PackedSparsePayload, QSGD, RandK,
                                     SignNorm, SparsePayload, TopK, _resolve_k)
+from repro.comm.stages import stage
 from repro.kernels import dispatch as kdispatch
 
 LANES = 128
@@ -348,10 +349,13 @@ def compress_packed(compressor: Compressor, key, spec: BucketSpec,
     one payload per bucket plus the dense per-leaf q (for the local EF
     update), so local and remote integration use the SAME quantized values.
     """
-    bufs = pack_leaves(spec, flat_leaves)
-    payloads, q_bufs = compress_bufs(compressor, key, spec, bufs,
-                                     backend=backend)
-    q_leaves = unpack_leaves(spec, q_bufs)
+    with stage("pack"):
+        bufs = pack_leaves(spec, flat_leaves)
+    with stage("compress"):
+        payloads, q_bufs = compress_bufs(compressor, key, spec, bufs,
+                                         backend=backend)
+    with stage("unpack"):
+        q_leaves = unpack_leaves(spec, q_bufs)
     return payloads, q_leaves
 
 

@@ -53,6 +53,7 @@ from typing import Callable, Optional, Tuple
 import jax
 
 from repro.comm.schedule import GossipSchedule
+from repro.comm.stages import stage
 from repro.core.compression import Compressor, Identity
 
 
@@ -74,11 +75,13 @@ def _pipelined_leaf_updates(leaves_x, leaves_s, leaves_hat, q_leaves,
     PRE-round (s, x_hat) carry, s integrates this round's payload for the
     next update.  Elementwise per leaf; XLA fuses these."""
     new_s, new_x = [], []
-    for lx, ls, lhat, qd, nb, g in zip(leaves_x, leaves_s, leaves_hat,
-                                       q_leaves, nbr_leaves, gammas):
-        new_x.append(lx + g * (ls - lhat).astype(lx.dtype))
-        sn = ls + (w_self * qd + w_nbr * nb).reshape(lx.shape).astype(ls.dtype)
-        new_s.append(sn)
+    with stage("ef_update"):
+        for lx, ls, lhat, qd, nb, g in zip(leaves_x, leaves_s, leaves_hat,
+                                           q_leaves, nbr_leaves, gammas):
+            new_x.append(lx + g * (ls - lhat).astype(lx.dtype))
+            sn = ls + (w_self * qd + w_nbr * nb).reshape(
+                lx.shape).astype(ls.dtype)
+            new_s.append(sn)
     return new_s, new_x
 
 
@@ -114,7 +117,7 @@ def make_pipelined_choco_fn(*, axes: Tuple[str, ...], sizes: Tuple[int, ...],
     """
     from repro.comm.gossip import (_LazyFlatIndex, _broadcast_gammas,
                                    _choco_leaf_updates, _flatten_states,
-                                   _neighbor_sum, _pack_align,
+                                   _mix, _pack_align,
                                    _packed_self_half, _per_leaf_self_half,
                                    _resolve_leaf_gammas, _self_weight,
                                    _weight_groups)
@@ -153,15 +156,15 @@ def make_pipelined_choco_fn(*, axes: Tuple[str, ...], sizes: Tuple[int, ...],
             payloads, q_leaves, new_hat = _packed_self_half(
                 compressor, tkey, leaves_x, leaves_hat, spec,
                 backend=kernel_backend)
-            if not groups:                     # n == 1: no neighbours
-                nbr_leaves, w_nbr = [q * 0.0 for q in q_leaves], 0.0
-            else:
-                dense_fn = lambda got: [bucket_dense(g, b) for g, b
-                                        in zip(got, spec.buckets)]
-                nbr_bufs, w_nbr = _neighbor_sum(payloads, groups, axis_arg,
-                                                dense_fn, flat_idx)
-                nbr_leaves = unpack_leaves(spec, nbr_bufs)
-            w_self = _self_weight(schedule, flat_idx)
+            dense_fn = lambda got: [bucket_dense(g, b) for g, b
+                                    in zip(got, spec.buckets)]
+            nbr_leaves, w_nbr = _mix(payloads, q_leaves, groups, axis_arg,
+                                     dense_fn, flat_idx)
+            if groups:                     # bucket space -> leaves
+                with stage("unpack"):
+                    nbr_leaves = unpack_leaves(spec, nbr_leaves)
+            with stage("mix"):
+                w_self = _self_weight(schedule, flat_idx)
             leaves_s, leaves_x = _pipelined_leaf_updates(
                 leaves_x, leaves_s, leaves_hat, q_leaves, nbr_leaves,
                 w_self, w_nbr, gammas)
@@ -183,14 +186,12 @@ def make_pipelined_choco_fn(*, axes: Tuple[str, ...], sizes: Tuple[int, ...],
             payloads, dense_fns, q_dense, new_hat = _per_leaf_self_half(
                 compressor, identity, exact_small_leaves,
                 small_leaf_threshold, tkey, leaves_x, leaves_hat)
-            if not groups:
-                nbr_sum, w_nbr = [q * 0.0 for q in q_dense], 0.0
-            else:
-                dense_fn = lambda got: [dfn(g) for dfn, g
-                                        in zip(dense_fns, got)]
-                nbr_sum, w_nbr = _neighbor_sum(payloads, groups, axis_arg,
-                                               dense_fn, flat_idx)
-            w_self = _self_weight(schedule, flat_idx)
+            dense_fn = lambda got: [dfn(g) for dfn, g
+                                    in zip(dense_fns, got)]
+            nbr_sum, w_nbr = _mix(payloads, q_dense, groups, axis_arg,
+                                  dense_fn, flat_idx)
+            with stage("mix"):
+                w_self = _self_weight(schedule, flat_idx)
             leaves_s, leaves_x = _pipelined_leaf_updates(
                 leaves_x, leaves_s, leaves_hat, q_dense, nbr_sum,
                 w_self, w_nbr, gammas)

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 
 from repro.core.compression import Compressor
 from repro.comm.schedule import GossipSchedule, round_recv_vec
+from repro.comm.stages import stage
 from repro.comm.gossip import (_LazyFlatIndex, _flatten_states, _pack_align,
                                _packed_self_half, _self_weight)
 
@@ -86,34 +87,39 @@ def make_pushsum_schedule_fn(*, axes: Tuple[str, ...], sizes: Tuple[int, ...],
             tkey = key if t == 0 else jax.random.fold_in(key, t)
             payloads, q_leaves, new_hat = _packed_self_half(
                 compressor, tkey, leaves_x, leaves_hat, spec)
-            a_self = _self_weight(schedule, flat_idx)
-            # in-band wire unit: (bucket payloads, weight scalar) — one
-            # ppermute pytree per round, the scalar rides along
-            wire = (payloads, w)
-            nbr_bufs = None
-            nbr_w = a_self * w
-            for rnd, recv in zip(schedule.rounds, recv_rows):
-                got_pl, got_w = jax.lax.ppermute(wire, axis_arg,
-                                                 list(rnd.perm))
-                a_recv = jnp.asarray(recv, jnp.float32)[flat_idx()]
-                bufs = [a_recv * bucket_dense(g, b)
-                        for g, b in zip(got_pl, spec.buckets)]
-                nbr_bufs = bufs if nbr_bufs is None else [
-                    acc + b for acc, b in zip(nbr_bufs, bufs)]
-                nbr_w = nbr_w + a_recv * got_w
-            if nbr_bufs is None:            # n == 1: A = [[1]]
-                nbr_leaves = [q * 0.0 for q in q_leaves]
-            else:
-                nbr_leaves = unpack_leaves(spec, nbr_bufs)
+            with stage("mix"):
+                a_self = _self_weight(schedule, flat_idx)
+                # in-band wire unit: (bucket payloads, weight scalar) — one
+                # ppermute pytree per round, the scalar rides along
+                wire = (payloads, w)
+                nbr_bufs = None
+                nbr_w = a_self * w
+                for rnd, recv in zip(schedule.rounds, recv_rows):
+                    got_pl, got_w = jax.lax.ppermute(wire, axis_arg,
+                                                     list(rnd.perm))
+                    a_recv = jnp.asarray(recv, jnp.float32)[flat_idx()]
+                    bufs = [a_recv * bucket_dense(g, b)
+                            for g, b in zip(got_pl, spec.buckets)]
+                    nbr_bufs = bufs if nbr_bufs is None else [
+                        acc + b for acc, b in zip(nbr_bufs, bufs)]
+                    nbr_w = nbr_w + a_recv * got_w
+                if nbr_bufs is None:            # n == 1: A = [[1]]
+                    nbr_leaves = [q * 0.0 for q in q_leaves]
+            if nbr_bufs is not None:
+                with stage("unpack"):
+                    nbr_leaves = unpack_leaves(spec, nbr_bufs)
             new_s, new_x = [], []
-            for lx, ls, qd, nb, nh in zip(leaves_x, leaves_s, q_leaves,
-                                          nbr_leaves, new_hat):
-                # s += a_ii q_i + sum_j a_ij q_j  (Algorithm-5 shape, A cols)
-                sn = ls + (a_self * qd + nb).reshape(lx.shape).astype(ls.dtype)
-                new_s.append(sn)
-                new_x.append(lx + gamma * (sn - nh).astype(lx.dtype))
-            leaves_s, leaves_x, leaves_hat = new_s, new_x, new_hat
-            w = w + gamma * (nbr_w - w).astype(w.dtype)
+            with stage("ef_update"):
+                for lx, ls, qd, nb, nh in zip(leaves_x, leaves_s, q_leaves,
+                                              nbr_leaves, new_hat):
+                    # s += a_ii q_i + sum_j a_ij q_j  (Algorithm-5 shape,
+                    # A cols)
+                    sn = ls + (a_self * qd + nb).reshape(
+                        lx.shape).astype(ls.dtype)
+                    new_s.append(sn)
+                    new_x.append(lx + gamma * (sn - nh).astype(lx.dtype))
+                leaves_s, leaves_x, leaves_hat = new_s, new_x, new_hat
+                w = w + gamma * (nbr_w - w).astype(w.dtype)
         unflatten = treedef.unflatten
         return (unflatten(leaves_x), unflatten(leaves_hat),
                 unflatten(leaves_s), w)

@@ -35,7 +35,7 @@ from repro.launch.env import place_compile_cache, simulate_host_devices
 from repro.obs.sinks import (DivergenceMonitor, JsonlSink, MetricLog,
                              StdoutSink)
 from repro.obs.timers import StepTimer
-from repro.obs.trace import ProfileSession
+from repro.obs.trace import ProfileSession, annotate
 
 # mirrors core.topology._TOPOLOGIES; kept literal so arg validation never
 # imports jax before XLA_FLAGS is set
@@ -516,37 +516,44 @@ def main(argv=None):
     timer.start()
     remaining = args.steps - resumed       # --steps is the TOTAL budget
     try:
+        # host phases are annotate() spans: under --profile-dir the trace
+        # puts each idle gap of the device down to the host work in it
         for i in range(remaining):
             prof.maybe_start(i)
-            state, mets = step_fn(state,
-                                  jax.tree.map(jnp.asarray, next_batch()))
+            with annotate("train:input"):
+                batch = jax.tree.map(jnp.asarray, next_batch())
+            with annotate("train:dispatch"):
+                state, mets = step_fn(state, batch)
             if i == 0 or i % 10 == 0 or i == remaining - 1:
-                # honest async-dispatch timing: block only on tap steps;
-                # the first (compiling) step is reported once as
-                # train/compile_s and never averaged into s/step
-                metrics = {"train/loss": float(mets["loss"]),
-                           "train/lr": float(mets["lr"]),
-                           "train/grad_norm": float(mets["grad_norm"]),
-                           "diag/node_loss_spread":
-                               float(mets["node_loss_spread"])}
-                blocker = lambda: jax.block_until_ready(state)
-                if i == 0:
-                    metrics["train/compile_s"] = timer.mark_compile(blocker)
-                else:
-                    sps = timer.tap(i, blocker)
-                    if sps is not None:
-                        metrics["train/s_per_step"] = sps
-                extra = {k: float(v) for k, v in mets.items()
-                         if k not in ("loss", "lr", "grad_norm",
-                                      "node_loss_spread")}
-                mlog.emit(int(state.step), metrics, extra=extra or None)
+                with annotate("train:tap"):
+                    # honest async-dispatch timing: block only on tap
+                    # steps; the first (compiling) step is reported once
+                    # as train/compile_s and never averaged into s/step
+                    metrics = {"train/loss": float(mets["loss"]),
+                               "train/lr": float(mets["lr"]),
+                               "train/grad_norm": float(mets["grad_norm"]),
+                               "diag/node_loss_spread":
+                                   float(mets["node_loss_spread"])}
+                    blocker = lambda: jax.block_until_ready(state)
+                    if i == 0:
+                        metrics["train/compile_s"] = timer.mark_compile(
+                            blocker)
+                    else:
+                        sps = timer.tap(i, blocker)
+                        if sps is not None:
+                            metrics["train/s_per_step"] = sps
+                    extra = {k: float(v) for k, v in mets.items()
+                             if k not in ("loss", "lr", "grad_norm",
+                                          "node_loss_spread")}
+                    mlog.emit(int(state.step), metrics, extra=extra or None)
             if diag_fn is not None and (i + 1) % args.diag_every == 0:
-                diag = {k: float(v) for k, v in diag_fn(state).items()}
-                diag["diag/gamma"] = buckets["gamma"]
-                diag["diag/wire_bytes_round"] = float(
-                    buckets["wire_bytes_round"])
-                diag["diag/data_skew_tv"] = float(next_batch.skew_tv)
-                mlog.emit(int(state.step), diag)
+                with annotate("train:diag"):
+                    diag = {k: float(v) for k, v in diag_fn(state).items()}
+                    diag["diag/gamma"] = buckets["gamma"]
+                    diag["diag/wire_bytes_round"] = float(
+                        buckets["wire_bytes_round"])
+                    diag["diag/data_skew_tv"] = float(next_batch.skew_tv)
+                    mlog.emit(int(state.step), diag)
                 xi = diag.get("diag/lyapunov",
                               diag["diag/consensus_dist"])
                 msg = monitor.update(int(state.step), xi) if monitor else None
@@ -558,9 +565,10 @@ def main(argv=None):
                     and (i + 1) % args.checkpoint_every == 0):
                 path = os.path.join(args.checkpoint_dir,
                                     f"step{int(state.step)}")
-                trainer.save_checkpoint(path, state,
-                                        metadata={"arch": cfg.name},
-                                        keep_last=args.keep_checkpoints)
+                with annotate("train:checkpoint"):
+                    trainer.save_checkpoint(path, state,
+                                            metadata={"arch": cfg.name},
+                                            keep_last=args.keep_checkpoints)
                 mlog.log(f"[train] checkpointed {path}")
             if prof.active and i + 1 >= prof.stop_after:
                 jax.block_until_ready(state)

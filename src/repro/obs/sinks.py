@@ -1,5 +1,5 @@
-"""Structured metric sinks: registered-schema records to JSONL / CSV /
-stdout, written off the hot loop by a background thread.
+"""Structured metric sinks: registered-schema records to JSONL / stdout,
+written off the hot loop by a background thread.
 
 Design (docs/ARCHITECTURE.md §Observability):
 
@@ -21,14 +21,13 @@ host-side by design: it is on the traced-purity exemption list, unlike
 """
 from __future__ import annotations
 
-import csv
 import json
 import os
 import queue
 import threading
 from typing import Callable, List, Optional, Sequence
 
-from repro.obs.schema import METRIC_SPECS, validate_record
+from repro.obs.schema import validate_record
 
 
 class Sink:
@@ -79,33 +78,6 @@ class JsonlSink(Sink):
     def write(self, record: dict) -> None:
         """Append one record as a JSON line."""
         self._f.write(json.dumps(record, sort_keys=True) + "\n")
-
-    def close(self) -> None:
-        """Flush and close the file (idempotent)."""
-        if not self._f.closed:
-            self._f.flush()
-            self._f.close()
-
-
-class CsvSink(Sink):
-    """Fixed-column CSV: ``kind, step`` plus every registered metric in
-    registry order — blank cells for metrics a record does not carry, so
-    the header never depends on emission order."""
-
-    def __init__(self, path: str):
-        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        self._cols = ["kind", "step"] + [m.name for m in METRIC_SPECS]
-        self._f = open(path, "a", encoding="utf-8", newline="")
-        self._w = csv.writer(self._f)
-        if self._f.tell() == 0:
-            self._w.writerow(self._cols)
-
-    def write(self, record: dict) -> None:
-        """Append one row (metrics records only — header/log records have
-        no tabular shape)."""
-        if record.get("kind") != "metrics":
-            return
-        self._w.writerow([record.get(c, "") for c in self._cols])
 
     def close(self) -> None:
         """Flush and close the file (idempotent)."""

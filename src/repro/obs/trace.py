@@ -11,11 +11,11 @@ launchers can import this module before XLA_FLAGS is frozen):
   ``--profile-steps``), skipping the compiling first step so the trace
   shows steady state, and emitting a TensorBoard-loadable trace dir.
 
-In-graph phase names (exchange/grad/optimizer) come from
-``jax.named_scope`` inside the trainer and are only enabled under
-``phase_scopes=True`` — named scopes land in HLO metadata, so the default
-path keeps the compiled train step byte-identical to the pre-telemetry
-HLO (the ``telemetry_off`` invariant).
+In-graph phase names (exchange/grad/optimizer, and the exchange's stages
+from ``comm/stages.py``) come from ``jax.named_scope`` and are only
+enabled under ``phase_scopes=True`` — named scopes land in HLO metadata,
+so the default path keeps the compiled train step byte-identical to the
+pre-telemetry HLO (the ``telemetry_off`` invariant).
 """
 from __future__ import annotations
 

@@ -22,6 +22,7 @@ from repro.configs.base import ModelConfig, ChocoConfig, parse_topology
 from repro.core.compression import make_compressor
 from repro.core.choco_gossip import GammaSpec, theorem2_stepsize
 from repro.core.topology import is_directed, make_topology, torus2d
+from repro.comm import stages
 from repro.comm.gossip import make_gossip_exchange
 from repro.comm.schedule import compile_directed_schedule, compile_schedules
 from repro.models.transformer import Model
@@ -575,7 +576,8 @@ class DecentralizedTrainer:
         pipelined = self.choco.pipeline_gossip and self.mode == "choco"
         # jax.named_scope lands in HLO op metadata, so phase names are
         # opt-in (--profile-dir): the default build keeps the compiled step
-        # byte-identical to the pre-telemetry HLO (telemetry_off invariant)
+        # byte-identical to the pre-telemetry HLO (telemetry_off invariant).
+        # The exchange's own stages (comm/stages.py) follow the same switch.
         scope = (jax.named_scope if phase_scopes
                  else (lambda name: contextlib.nullcontext()))
 
@@ -590,7 +592,7 @@ class DecentralizedTrainer:
             # backward matmuls (benchmarks/bench_overlap.py audits this).
             gkey = jax.random.fold_in(state.key, state.step)
             exchange = self._exchange(state.params)
-            with scope("obs:exchange"):
+            with scope("obs:exchange"), stages.scoped(phase_scopes):
                 gx, new_hat, new_s = exchange(gkey, state.params,
                                               state.x_hat, state.s)
 
@@ -654,7 +656,7 @@ class DecentralizedTrainer:
             # 3. gossip exchange (CHOCO / plain / all-reduce / push-sum)
             gkey = jax.random.fold_in(state.key, state.step)
             exchange = self._exchange(state.params)   # specs from leaf ndims
-            with scope("obs:exchange"):
+            with scope("obs:exchange"), stages.scoped(phase_scopes):
                 if pushsum:
                     new_params, new_hat, new_s, new_w = exchange(
                         gkey, x_half, state.x_hat, state.s, state.psw)

@@ -3,7 +3,7 @@
 Fast tier:
   * schema — record validation against the metric registry (reserved
     keys, unregistered keys, non-scalar values);
-  * sinks — JSONL/CSV/stdout writers, the background MetricLog drains on
+  * sinks — JSONL/stdout writers, the background MetricLog drains on
     close, validation errors surface at the emit call site;
   * divergence monitor — convergence-floor wobble never trips, sustained
     Lyapunov growth does;
@@ -20,8 +20,8 @@ and --metrics-dir emits a JSONL run log in which every record validates
 against the registry (header + compile-once + steady-state taps + diag
 records).
 """
-import csv
 import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +33,7 @@ from repro.core.choco_gossip import (auto_stepsize,
 from repro.core.compression import make_compressor
 from repro.core.topology import make_topology
 from repro.obs.schema import METRIC_SPECS, METRICS, validate_record
-from repro.obs.sinks import (CsvSink, DivergenceMonitor, JsonlSink,
+from repro.obs.sinks import (DivergenceMonitor, JsonlSink,
                              MetricLog, StdoutSink)
 from repro.obs.timers import StepTimer, percentile
 from repro.obs.trace import ProfileSession, annotate
@@ -98,18 +98,6 @@ def test_metric_log_validates_on_calling_thread(tmp_path):
             mlog.emit(0, {"train/nonsense": 1.0})
     finally:
         mlog.close()
-
-
-def test_csv_sink_writes_fixed_columns(tmp_path):
-    path = str(tmp_path / "m.csv")
-    with MetricLog([CsvSink(path)]) as mlog:
-        mlog.header(skipped="csv ignores headers")
-        mlog.emit(1, {"train/loss": 2.5, "train/lr": 0.1})
-        mlog.emit(2, {"train/loss": 2.25})
-    rows = list(csv.DictReader(open(path)))
-    assert [r["step"] for r in rows] == ["1", "2"]
-    assert rows[0]["train/loss"] == "2.5" and rows[0]["train/lr"] == "0.1"
-    assert rows[1]["train/lr"] == ""       # absent metric -> empty cell
 
 
 def test_stdout_sink_formatter_skips_none(capsys):
@@ -189,6 +177,33 @@ def test_profile_session_noop_without_dir(tmp_path):
         ProfileSession(str(tmp_path), n_steps=0)
     with annotate("obs:test"):      # degrades to a no-op context
         pass
+
+
+@pytest.mark.slow
+def test_launcher_profile_window_records_host_spans(tmp_path):
+    """Under --profile-dir the trace holds the loop's host phases as
+    spans, on the device trace's clock."""
+    import glob
+    from jax.profiler import ProfileData
+    from repro.launch.train import main
+    prof = str(tmp_path / "prof")
+    assert main(["--arch", "qwen3-1.7b", "--smoke", "--seq-len", "16",
+                 "--batch-per-node", "1", "--steps", "4",
+                 "--compressor", "top_k", "--fraction", "0.05",
+                 "--diag-every", "1",
+                 "--checkpoint-dir", str(tmp_path / "ckpt"),
+                 "--checkpoint-every", "3",
+                 "--profile-dir", prof, "--profile-steps", "3"]) == 0
+    found = glob.glob(os.path.join(prof, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(found) == 1, found
+    names = {e.name for plane in ProfileData.from_file(found[0]).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    # traced steps 1..3: input, dispatch and diag each step, the
+    # checkpoint after step 2, the tap on the last step
+    assert {"train:input", "train:dispatch", "train:tap", "train:diag",
+            "train:checkpoint"} <= names
 
 
 # --------------------------------------------------------------------------
