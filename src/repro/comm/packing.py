@@ -29,9 +29,8 @@ dtype-homogeneous flat *buckets*:
 Layout rules: buckets are keyed by (dtype, exact?, route) and split when
 they would exceed ``max_bucket_elems`` (bounds top_k width and latency).  A
 single leaf larger than the cap cannot be split — it gets a dedicated
-bucket, and the TopK path falls back to the legacy row-blockwise selection
-so no individual top_k ever exceeds ``MAX_BUCKET_ELEMS`` lanes (int32-safe
-within-block indices).
+bucket, and the TopK path selects row-blockwise, so no top-k row ever
+exceeds ``MAX_BUCKET_ELEMS`` lanes (int32-safe within-row indices).
 """
 from __future__ import annotations
 
@@ -244,8 +243,11 @@ def compress_bucket(compressor: Compressor, key, buf: jax.Array,
 
     Dispatches to the block-kernel paths (one launch per bucket):
       * BlockTopK  -> batched blockwise top-k  (kernels/ops.block_topk_select)
-      * TopK       -> one global lax.top_k with k resolved from the bucket's
-                      logical size (sum of leaf sizes, padding excluded)
+      * TopK       -> exact top-k by threshold select
+                      (kernels/ops.topk_threshold_select: the index set of
+                      lax.top_k, listed by ascending index), k resolved per
+                      slot; a bucket over MAX_BUCKET_ELEMS is cut into rows
+                      of that width, each keeping an equal share of k
       * RandK      -> per-slot budget, sampled over logical positions only
       * QSGD       -> the int8/int16 quantize codes of kernels/qsgd.py
                       (fused pallas launch or the ref-exact jnp inline)
@@ -270,18 +272,20 @@ def compress_bucket(compressor: Compressor, key, buf: jax.Array,
             vals = vals * (bucket.logical / k)
         return SparsePayload(vals, idx.astype(jnp.int32), buf.size)
     if isinstance(compressor, TopK):
+        from repro.kernels.ops import topk_threshold_select
         k = _slot_budget(compressor, slots, bucket)
         if buf.size > MAX_BUCKET_ELEMS:
-            # oversized single-leaf bucket (spec cannot split a leaf): fall
-            # back to the legacy row-blockwise selection — bounded top_k
-            # width, int32-safe within-block indices
-            from repro.kernels.ops import block_topk_select
-            n_blocks = -(-buf.size // MAX_BUCKET_ELEMS)
-            kb = max(1, -(-k // n_blocks))
-            vals, idx = block_topk_select(buf, kb, block=MAX_BUCKET_ELEMS)
+            # oversized single-leaf bucket (spec cannot split a leaf):
+            # row-blockwise selection over rows of MAX_BUCKET_ELEMS —
+            # bounded row width, int32-safe within-row indices
+            n_rows = -(-buf.size // MAX_BUCKET_ELEMS)
+            kb = max(1, -(-k // n_rows))
+            rows = jnp.pad(buf, (0, n_rows * MAX_BUCKET_ELEMS - buf.size))
+            vals, idx = topk_threshold_select(
+                rows.reshape(n_rows, MAX_BUCKET_ELEMS), kb)
             return PackedSparsePayload(vals, idx, buf.size, MAX_BUCKET_ELEMS)
-        _, idx = jax.lax.top_k(jnp.abs(buf), k)
-        return SparsePayload(buf[idx], idx.astype(jnp.int32), buf.size)
+        vals, idx = topk_threshold_select(buf[None], k)
+        return SparsePayload(vals[0], idx[0], buf.size)
     if isinstance(compressor, QSGD):
         # elementwise codes via kernels/dispatch.py (fused pallas launch
         # or the bit-exact jnp inline); the norm reduction stays here, on
@@ -413,6 +417,32 @@ def bucket_wire_bits(spec: BucketSpec, compressor: Compressor) -> List[int]:
         else:
             bits.append(compressor.wire_bits(b.size))
     return [int(x) for x in bits]
+
+
+def bucket_selection(spec: BucketSpec, compressor: Compressor
+                     ) -> List[Optional[dict]]:
+    """How each bucket's top-k picks its coordinates, in bucket order —
+    ``{"selection", "rows", "k"}`` (k kept per row) or None where nothing
+    is selected by magnitude.  Mirrors :func:`compress_bucket`: TopK runs
+    the sort-free threshold select, BlockTopK a ``lax.top_k`` (a sort)
+    per ``block``-wide row."""
+    out: List[Optional[dict]] = []
+    for b in spec.buckets:
+        if b.exact:
+            out.append(None)
+        elif isinstance(compressor, TopK):
+            k = _slot_budget(compressor, spec.bucket_slots(b.index), b)
+            rows = (-(-b.size // MAX_BUCKET_ELEMS)
+                    if b.size > MAX_BUCKET_ELEMS else 1)
+            out.append({"selection": "threshold", "rows": rows,
+                        "k": max(1, -(-k // rows))})
+        elif isinstance(compressor, BlockTopK):
+            out.append({"selection": "sort",
+                        "rows": -(-b.size // compressor.block),
+                        "k": compressor._kb()})
+        else:
+            out.append(None)
+    return out
 
 
 def packed_wire_bits(spec: BucketSpec, compressor: Compressor) -> int:

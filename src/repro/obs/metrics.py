@@ -124,8 +124,10 @@ def jitted_diagnostics(trainer, state_shape):
 
 def bucket_telemetry(trainer) -> dict:
     """Host-side static telemetry for the run header: per-bucket wire
-    bytes and effective Theorem-2 gamma of the packed exchange (empty
-    bucket list for per-leaf / uncompressed modes)."""
+    bytes, effective Theorem-2 gamma and, for top-k compressors, the
+    selection path (``threshold`` or ``sort``) with its rows and k per
+    row, of the packed exchange (empty bucket list for per-leaf /
+    uncompressed modes)."""
     out = {"gamma": float(trainer.gamma), "wire_bytes_round": 0,
            "buckets": []}
     if trainer.compressor is None:
@@ -135,16 +137,19 @@ def bucket_telemetry(trainer) -> dict:
         out["wire_bytes_round"] = int(
             trainer.compressor.wire_bits(1 << 20)) // 8
         return out
-    from repro.comm.packing import bucket_omegas, bucket_wire_bits
+    from repro.comm.packing import (bucket_omegas, bucket_selection,
+                                    bucket_wire_bits)
     omegas = bucket_omegas(spec, trainer.compressor)
     bits = bucket_wire_bits(spec, trainer.compressor)
-    for b, omega, wb in zip(spec.buckets, omegas, bits):
+    picks = bucket_selection(spec, trainer.compressor)
+    for b, omega, wb, pick in zip(spec.buckets, omegas, bits, picks):
         gamma = (trainer.gamma_spec.value(omega)
                  if trainer.gamma_spec is not None else trainer.gamma)
         out["buckets"].append({
             "index": int(b.index), "elems": int(b.logical),
             "exact": bool(b.exact), "omega": float(omega),
-            "gamma": float(gamma), "wire_bytes": int(wb) // 8})
+            "gamma": float(gamma), "wire_bytes": int(wb) // 8,
+            **(pick or {})})
     out["wire_bytes_round"] = sum(e["wire_bytes"]
                                   for e in out["buckets"])
     return out

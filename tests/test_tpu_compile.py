@@ -129,3 +129,19 @@ def test_pallas_exchange_compiles_under_shard_map_on_4_chips(
     jax.clear_caches()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+
+
+def test_topk_threshold_select_compiles_for_v5e_without_a_sort(
+        one_chip, buckets):
+    """The packed TopK path on the largest per-layer bucket (rows of 4 Mi
+    elements) compiles for the chip, and no sort is left in it."""
+    from repro.comm.packing import compress_bucket, make_bucket_spec
+    from repro.core.compression import TopK
+    spec = make_bucket_spec([jax.ShapeDtypeStruct((buckets["layer"],),
+                                                  jnp.float32)])
+    buf = jax.ShapeDtypeStruct((buckets["layer"],), jnp.float32,
+                               sharding=one_chip)
+    fn = lambda b: compress_bucket(TopK(fraction=0.01), None, b,
+                                   spec.buckets[0], spec.bucket_slots(0))
+    text = jax.jit(fn).lower(buf).compile().as_text()
+    assert " sort(" not in text and "top_k" not in text
