@@ -85,6 +85,7 @@ def gaps(prog: Dict, ref: Dict) -> Dict[str, float]:
 
 def judge(values: Dict[str, float], limits: Dict[str, float]) -> bool:
     """True when every number that has a limit is finite and within it.
-    A number a cell's limits leave out is read and printed, not compared."""
-    return all(np.isfinite(values[k]) and values[k] <= v
-               for k, v in limits.items())
+    A number a cell's limits leave out is read and printed, not compared;
+    a cell with no limits is never correct."""
+    return bool(limits) and all(np.isfinite(values[k]) and values[k] <= v
+                                for k, v in limits.items())

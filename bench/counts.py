@@ -5,7 +5,8 @@ section of ``bench/configs/<config>.json``) and a traffic mix, never read
 from the program, so a later change to the program cannot move it.
 
 * Parameter leaves and their sizes, in the order ``jax.tree`` flattens the
-  parameter dict of the dense decoder (sorted keys).
+  parameter dict that the model's family (``bench/families``) describes
+  (sorted keys).
 * The compressor's bucket layout (:func:`bucket_plan`): the packing rule of
   the CHOCO exchange as the configuration runs it.  Leaves in flatten
   order, each padded to 128 elements, share a bucket until it would pass
@@ -22,6 +23,8 @@ import math
 import re
 from typing import Dict, List, Tuple
 
+from bench import families
+
 LANES = 128
 #: largest bucket (and top-k row) of the packed exchange, in elements
 MAX_BUCKET = 1 << 22
@@ -29,23 +32,9 @@ F32 = 4
 
 
 def param_shapes(model: Dict) -> Dict:
-    """One node's parameter shapes, as the nested dict the dense decoder
+    """One node's parameter shapes, as the nested dict the model's family
     holds them in (a node's own copy; the trainer stacks nodes first)."""
-    D, V, F = model["d_model"], model["vocab_size"], model["d_ff"]
-    H, KV, L = model["n_heads"], model["n_kv_heads"], model["n_layers"]
-    Dh = model["head_dim"]
-    embed = {"final_norm": (D,), "tok": (V, D)}
-    if not model["tie_embeddings"]:
-        embed["unembed"] = (D, V)
-    attn = {"wq": (L, D, H * Dh), "wk": (L, D, KV * Dh),
-            "wv": (L, D, KV * Dh), "wo": (L, H * Dh, D)}
-    if model["qk_norm"]:
-        attn["q_norm"] = (L, Dh)
-        attn["k_norm"] = (L, Dh)
-    layer = {"attn": attn, "ln1": (L, D), "ln2": (L, D),
-             "mlp": {"w_gate": (L, D, F), "w_up": (L, D, F),
-                     "w_down": (L, F, D)}}
-    return {"embed": embed, "stack": {"p0": layer}, "tail": {}}
+    return families.load(model["family"]).param_shapes(model)
 
 
 def leaf_shapes(model: Dict) -> List[Tuple[str, Tuple[int, ...]]]:
@@ -123,20 +112,9 @@ def ef_update_bytes(model: Dict) -> int:
 
 
 def flops_per_token(model: Dict, seq_len: int) -> float:
-    """Forward + backward operations per token, recomputation excluded.
-
-    Matmuls: 6 per multiply-add of every weight a token passes through
-    (the embedding gather is free, the head is a matmul).  Attention: the
-    causal scores and the weighted sum, 2 matmuls of d_head per head
-    over the keys a query sees, on average (S + 1) / 2, times 3 for
-    forward and backward: 6 * L * H * Dh * (S + 1) / 2 * 2."""
-    D, V, F = model["d_model"], model["vocab_size"], model["d_ff"]
-    H, KV, L = model["n_heads"], model["n_kv_heads"], model["n_layers"]
-    Dh = model["head_dim"]
-    per_layer = D * H * Dh * 2 + D * KV * Dh * 2 + 3 * D * F
-    matmul_params = L * per_layer + V * D
-    attn = 6 * L * H * Dh * (seq_len + 1)
-    return 6.0 * matmul_params + attn
+    """Forward + backward operations per token, recomputation excluded,
+    by the model's family."""
+    return families.load(model["family"]).flops_per_token(model, seq_len)
 
 
 _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
@@ -144,6 +122,8 @@ _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
                 "f64": 8, "f8e4m3fn": 1, "f8e5m2": 1}
 _ARRAY_RE = re.compile(r"\b([a-z]+[0-9]*[a-z0-9]*)\[([0-9,]*)\]")
 _PERMUTE = re.compile(r"\scollective-permute(?:-start)?\(")
+_DEFINES = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (.*?) [a-z][\w\-]*\(")
+_OPERAND = re.compile(r"%([\w.\-]+)")
 
 
 def _array_bytes(text: str) -> int:
@@ -172,11 +152,15 @@ def _operands(line: str, start: int) -> str:
 
 def wire_bytes(hlo_text: str) -> int:
     """Bytes one device sends per execution of a compiled SPMD program:
-    the operands of every collective-permute (each goes to one peer),
-    with their types as the instruction's own operand list prints them."""
-    total = 0
+    the operands of every collective-permute (each goes to one peer), each
+    with the type that the instruction defining it gives (the compiled
+    text names an operand without its type)."""
+    types, sent = {}, []
     for line in hlo_text.splitlines():
+        d = _DEFINES.match(line)
+        if d:
+            types[d.group(1)] = d.group(2)
         m = _PERMUTE.search(line)
         if m:
-            total += _array_bytes(_operands(line, m.end() - 1))
-    return total
+            sent += _OPERAND.findall(_operands(line, m.end() - 1))
+    return sum(_array_bytes(types[name]) for name in sent)

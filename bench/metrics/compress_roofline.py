@@ -1,9 +1,9 @@
 """Compression: the least time of one node's selection, reading every
 parameter's delta once and writing the kept values and int32 indices
-(``counts.topk_bytes``, the work ``topk_roofline`` counts) at peak HBM
-bandwidth, over ``compress_ms``, the device time of the exchange's
-``compress`` stage, in %.  Timed by the stage and not by an op name, it
-reads whatever kernel does the selection."""
+(``counts.topk_bytes``) at peak HBM bandwidth, over ``compress_ms``, the
+device time of the exchange's ``compress`` stage, in %.  Timed by the
+stage and not by an op name, it reads whatever kernel does the
+selection."""
 from bench import counts, peaks
 from bench.metrics import compress_ms
 

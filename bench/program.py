@@ -97,8 +97,10 @@ def seeded_state(prog: Program, model: Dict, key):
     if (jax.tree.structure(ours) != jax.tree.structure(shape.params)
             or [a.shape for a in jax.tree.leaves(ours)]
             != [a.shape for a in jax.tree.leaves(shape.params)]):
-        raise ValueError("the program's parameters are not the dense "
-                         "decoder's that the reference describes: "
+        family = model["family"]
+        raise ValueError(f"the program's parameters are not those of the "
+                         f"{family!r} family that bench/families/{family}.py "
+                         f"describes: "
                          f"{jax.tree.map(lambda a: a.shape, shape.params)}")
     fill = jax.jit(lambda st, k: st._replace(
         params=reference.init_nodes(model, k, trainer.n_nodes)),

@@ -1,13 +1,11 @@
-"""Plain reference of one CHOCO-SGD step of the dense decoder.
+"""Plain reference of one CHOCO-SGD step.
 
 Written from the configuration's sizes and the published algorithms, in
 float32 at ``Precision.HIGHEST``, importing nothing of the program:
 
-* the decoder (pre-norm RMSNorm with a (1 + w) gain, rotary embeddings,
-  optional QK-norm, grouped-query causal attention, SwiGLU, tied or
-  untied head) and its mean next-token cross-entropy, differentiated by
-  ``jax.grad``; attention runs over blocks of queries and the loss over
-  blocks of tokens so that one chip holds a full-width step;
+* the model and its mean next-token cross-entropy, from the module of the
+  configuration's family (``bench/families/<family>.py``), differentiated
+  by ``jax.grad``;
 * heavy-ball momentum (beta 0.9) for the local half-step;
 * CHOCO-GOSSIP (Koloskova et al. 2019, Algorithm 2) with top-k of the
   error-feedback delta per bucket of ``counts.bucket_plan``, each message
@@ -18,9 +16,9 @@ The same code, with every matmul's operands and cotangents rounded to
 float8 (e4m3, one scale per tensor), is the control: what a step one
 precision below the configuration's bfloat16 would give.
 
-Weights are made here, from the seed, by :func:`init_node`; the harness
-places the same weights in the program's state, so nothing of the
-program's own making reaches the reference.
+Weights are made here, from the seed, by :func:`init_node` with the
+family's rule; the harness places the same weights in the program's
+state, so nothing of the program's own making reaches the reference.
 """
 from __future__ import annotations
 
@@ -32,40 +30,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import compare, counts
+from bench import compare, counts, families
 
 HIGHEST = jax.lax.Precision.HIGHEST
 MOMENTUM = 0.9
-#: queries per attention block and tokens per loss block
-BLOCK = 512
-NORMS = ("final_norm", "ln1", "ln2", "q_norm", "k_norm")
 FAULTS = ("half_batch", "altered", "no_exchange")
 
 
-def check_supported(model: Dict) -> None:
-    """Raise for a configuration this reference does not describe."""
-    want = {"family": "dense", "mlp_type": "swiglu", "causal": True,
-            "attn_logit_softcap": None, "final_logit_softcap": None,
-            "sliding_window": None, "local_global_pattern": 0,
-            "scale_embed": False, "moe": None, "ssm": None, "hybrid": None,
-            "frontend": None}
-    bad = {k: model.get(k) for k, v in want.items() if model.get(k) != v}
-    if bad:
-        raise ValueError(f"the reference describes the dense decoder only; "
-                         f"this configuration has {bad}")
-
-
 # -- weights -----------------------------------------------------------------
-
-def _leaf_init(path: str, shape, key):
-    name = path.rsplit("/", 1)[-1]
-    if name in NORMS:
-        return jnp.zeros(shape, jnp.float32)
-    z = jax.random.normal(key, shape, jnp.float32)
-    if name == "tok":
-        return z * 0.02
-    return z / math.sqrt(shape[-2])
-
 
 def _unflatten(model: Dict, leaves: Sequence):
     shapes = counts.param_shapes(model)
@@ -83,7 +55,8 @@ def node_key(key, node: int, leaf: int):
 
 def init_node(model: Dict, key, node) -> Dict:
     """Node ``node``'s initial weights from ``key`` (nested dict, f32)."""
-    leaves = [_leaf_init(path, shape, node_key(key, node, j))
+    init_leaf = families.load(model["family"]).init_leaf
+    leaves = [init_leaf(path, shape, node_key(key, node, j))
               for j, (path, shape) in enumerate(counts.leaf_shapes(model))]
     return _unflatten(model, leaves)
 
@@ -93,7 +66,7 @@ def init_nodes(model: Dict, key, n_nodes: int) -> Dict:
     return jax.vmap(lambda i: init_node(model, key, i))(jnp.arange(n_nodes))
 
 
-# -- forward -----------------------------------------------------------------
+# -- loss --------------------------------------------------------------------
 
 def _q8(a):
     """Round to float8 e4m3 with one scale per tensor."""
@@ -128,92 +101,13 @@ def _matmul(spec: str, a, b, control: bool):
     return _round_cotangent(jnp.einsum(spec, ra, rb, precision=HIGHEST))
 
 
-def _rms(x, w, eps):
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * (1.0 + w)
-
-
-def _rope(x, theta):
-    """x: (B, S, heads, Dh); rotate-half rotary embedding."""
-    S, Dh = x.shape[1], x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, Dh, 2, dtype=jnp.float32) / Dh))
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv
-    sin, cos = jnp.sin(ang)[:, None, :], jnp.cos(ang)[:, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _attention(q, k, v, control):
-    """q (B,S,KV,R,Dh), k/v (B,S,KV,Dh): causal attention by query blocks."""
-    B, S, KV, R, Dh = q.shape
-    blk = min(BLOCK, S)
-    nb = S // blk
-    qb = jnp.moveaxis(q.reshape(B, nb, blk, KV, R, Dh), 1, 0)
-    kpos = jnp.arange(S)
-
-    def one(args):
-        qi, i = args
-        sc = _matmul("bqkrd,bskd->bkrqs", qi, k, control) / math.sqrt(Dh)
-        qpos = i * blk + jnp.arange(blk)
-        mask = kpos[None, :] <= qpos[:, None]
-        sc = jnp.where(mask, sc, -jnp.inf)
-        w = jax.nn.softmax(sc, axis=-1)
-        return _matmul("bkrqs,bskd->bqkrd", w, v, control)
-
-    out = jax.lax.map(jax.checkpoint(one), (qb, jnp.arange(nb)))
-    return jnp.moveaxis(out, 0, 1).reshape(B, S, KV * R * Dh)
-
-
 def node_loss(params: Dict, tokens, labels, model: Dict,
               control: bool = False, valid=None):
-    """Mean next-token cross-entropy of one node's batch (B, S)."""
-    B, S = tokens.shape
-    D, H, KV = model["d_model"], model["n_heads"], model["n_kv_heads"]
-    Dh, eps = model["head_dim"], model["norm_eps"]
-    emb = params["embed"]
-    x = jnp.take(emb["tok"], tokens, axis=0)
-
-    def layer(x, p):
-        a = p["attn"]
-        h = _rms(x, p["ln1"], eps)
-        q = _matmul("bsd,de->bse", h, a["wq"], control).reshape(B, S, H, Dh)
-        k = _matmul("bsd,de->bse", h, a["wk"], control).reshape(B, S, KV, Dh)
-        v = _matmul("bsd,de->bse", h, a["wv"], control).reshape(B, S, KV, Dh)
-        if model["qk_norm"]:
-            q = _rms(q, a["q_norm"], eps)
-            k = _rms(k, a["k_norm"], eps)
-        q = _rope(q, model["rope_theta"]).reshape(B, S, KV, H // KV, Dh)
-        k = _rope(k, model["rope_theta"])
-        o = _attention(q, k, v, control)
-        x = x + _matmul("bse,ed->bsd", o, a["wo"], control)
-        m = p["mlp"]
-        y = _rms(x, p["ln2"], eps)
-        g = _matmul("bsd,df->bsf", y, m["w_gate"], control)
-        u = _matmul("bsd,df->bsf", y, m["w_up"], control)
-        x = x + _matmul("bsf,fd->bsd", jax.nn.silu(g) * u, m["w_down"],
-                        control)
-        return x, None
-
-    x, _ = jax.lax.scan(jax.checkpoint(layer), x, params["stack"]["p0"])
-    h = _rms(x, emb["final_norm"], eps)
-    head = emb["tok"].T if model["tie_embeddings"] else emb["unembed"]
-    if valid is None:
-        valid = jnp.ones((B, S), jnp.float32)
-    blk = min(BLOCK, S)
-    nb = S // blk
-    hc = jnp.moveaxis(h.reshape(B, nb, blk, D), 1, 0)
-    lc = jnp.moveaxis(labels.reshape(B, nb, blk), 1, 0)
-    vc = jnp.moveaxis(valid.reshape(B, nb, blk), 1, 0)
-
-    def nll(args):
-        hh, ll, vv = args
-        logits = _matmul("bcd,dv->bcv", hh, head, control)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, ll[..., None], axis=-1)[..., 0]
-        return jnp.sum((lse - tgt) * vv)
-
-    total = jnp.sum(jax.lax.map(jax.checkpoint(nll), (hc, lc, vc)))
-    return total / jnp.maximum(jnp.sum(valid), 1.0)
+    """Mean next-token cross-entropy of one node's batch (B, S), by the
+    model's family, every matmul at ``HIGHEST`` or as the control."""
+    mm = functools.partial(_matmul, control=control)
+    return families.load(model["family"]).loss(params, tokens, labels,
+                                               model, mm, valid)
 
 
 # -- CHOCO -------------------------------------------------------------------
@@ -266,7 +160,7 @@ def compress(deltas: List, plan: List[Dict]):
 
 def scatter_add(leaves: List, payload, plan: List[Dict], weight) -> List:
     """``leaves`` plus ``weight`` times the payloads' kept values, added in
-    place where they were taken (no dense copy of a message is made)."""
+    place where they were taken (no full-size copy of a message is made)."""
     leaves = list(leaves)
     for (vals, idx), b in zip(payload, plan):
         flat = (jnp.arange(b["rows"])[:, None] * _row_width(b) + idx)
@@ -291,7 +185,7 @@ class Reference:
 
     def __init__(self, model: Dict, traffic: Dict, lr: float, devices):
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        check_supported(model)
+        families.load(model["family"]).check(model)
         self.model = model
         self.lr = np.float32(lr)
         self.n = n = traffic["nodes"]

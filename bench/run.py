@@ -234,7 +234,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float):
             f.write(hlo)
         red = btrace.reduce(trace_dir, hlo)
         ctx = btrace.Context(cell=cell, reduced=red, tokens_per_s=rate,
-                             device_kind=devs[0].device_kind)
+                             device_kind=devs[0].device_kind, hlo=hlo)
         for m in cell.per_layer:
             value = btrace.read_metric(m["name"], ctx)
             if value is not None:

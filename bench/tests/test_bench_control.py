@@ -163,3 +163,12 @@ def test_limit_rule():
     assert fails                      # the control fails step and step_proj
     upper["control"] = {k: v / 10 for k, v in upper["control"].items()}
     assert not limits(lower, upper)[2]
+
+
+def test_no_limits_is_never_correct():
+    """A cell whose limits file holds no number compares nothing and is
+    not correct, whatever it reads."""
+    from bench import compare
+    assert not compare.judge({"loss_gap": 0.0}, {})
+    assert compare.judge({"loss_gap": 0.0}, {"loss_gap": 1e-3})
+    assert not compare.judge({"loss_gap": float("nan")}, {"loss_gap": 1e-3})

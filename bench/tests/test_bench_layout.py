@@ -76,6 +76,25 @@ def test_no_file_without_its_name(spec):
     assert stems("configs", ".json") == {c["name"] for c in spec["configs"]}
 
 
+def test_every_family_has_its_module(spec):
+    """Each configuration's model family has its yardstick in
+    bench/families/<family>.py, with the whole interface, and no family
+    module ships that no configuration uses."""
+    from bench import families
+    used = set()
+    for c in spec["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            used.add(json.load(f)["model"]["family"])
+    shipped = {f[:-3] for f in os.listdir(os.path.join(BENCH, "families"))
+               if f.endswith(".py") and not f.startswith("_")}
+    assert shipped == used
+    for family in used:
+        module = families.load(family)
+        for fn in ("check", "param_shapes", "init_leaf", "loss",
+                   "flops_per_token"):
+            assert callable(getattr(module, fn)), (family, fn)
+
+
 def test_names_and_units(spec):
     names = [c["name"] for c in spec["configs"]]
     names += [w["name"] for w in spec["workloads"]]

@@ -4,8 +4,8 @@ Hand-made op lists pin which stage an op belongs to (the stage may sit
 below a ``lax.switch`` branch component, the first stage component wins,
 ``obs:grad`` has no stages) and what each reader makes of them.  On the
 trace recorded on the chip before the program opened stage scopes, the
-stage readers read nothing, and the time no scope claims is the recorded
-figure.
+stage readers read nothing, and the time no scope claims
+(``unscoped_ms``) is the recorded figure.
 """
 import gzip
 import os
@@ -66,7 +66,8 @@ SCOPED = [_op(0, 40, f"{EX}/pack/reshape"),
 
 
 @pytest.mark.parametrize("metric, ns", [
-    ("compress_ms", 2600), ("pack_ms", 100), ("ef_update_ms", 400)])
+    ("compress_ms", 2600), ("pack_ms", 100), ("ef_update_ms", 400),
+    ("mix_ms", 20), ("unscoped_ms", 80)])
 def test_stage_readers(metric, ns):
     # two steps: each reads its stage's time over the steps, in ms
     assert trace.read_metric(metric, _ctx(SCOPED)) == ns / 2 / 1e6
@@ -91,7 +92,7 @@ def test_stages_and_the_rest_cover_the_exchange():
 
 
 @pytest.mark.parametrize("metric", ["compress_ms", "compress_roofline",
-                                    "pack_ms", "ef_update_ms"])
+                                    "pack_ms", "ef_update_ms", "mix_ms"])
 def test_no_stage_scopes_read_nothing(metric):
     unstaged = [_op(0, 50, f"{EX}/jit(block_topk_select)/top_k", "sort"),
                 _op(50, 5, f"{EX}/reshape"), _op(55, 5, "", "copy")]
@@ -112,7 +113,7 @@ def recorded():
 
 
 @pytest.mark.parametrize("metric", ["compress_ms", "compress_roofline",
-                                    "pack_ms", "ef_update_ms"])
+                                    "pack_ms", "ef_update_ms", "mix_ms"])
 def test_recorded_trace_has_no_stages(recorded, metric):
     """The recorded run's program opened no stage scopes."""
     assert trace.read_metric(metric, recorded) is None
@@ -123,3 +124,10 @@ def test_recorded_trace_unscoped_time(recorded):
     of qwen's step under no scope (TPU v5 lite, the recorded run)."""
     ms = trace.per_step_max(recorded.reduced, stages.unscoped)
     assert ms == pytest.approx(75.6, abs=0.1)
+
+
+def test_unscoped_reader_on_the_recorded_trace(recorded):
+    """``unscoped_ms`` reads the time no scope claims: 75.63 ms per step of
+    the recorded run (TPU v5 lite)."""
+    assert trace.read_metric("unscoped_ms", recorded) == \
+        pytest.approx(75.62938833333332, rel=1e-12)

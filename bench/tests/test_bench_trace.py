@@ -2,11 +2,13 @@
 
 On a trace recorded on the chip (``bench/testdata``: a traced run of
 qwen3-1.7b.topk.1chip, its compiled HLO text and the run's result line),
-the readers give again exactly the per-layer numbers the run printed.
+the readers of the metrics the cell declares give again exactly the
+per-layer numbers the run printed.
 Small hand-made cases pin the interval arithmetic, the scope mapping and
 how the readers treat a collective permute, which the one-chip trace has
 none of.
 """
+import dataclasses
 import gzip
 import json
 import os
@@ -80,6 +82,50 @@ def test_collective_and_host_gap_readers():
                     "bench:wait": 10e-9}
 
 
+#: a ring step as the chip's compiler prints it: operands are named, not
+#: typed, and a permute may be asynchronous (start / done) with a tuple type
+RING_HLO = """\
+HloModule jit_train_step
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %vals = f32[75,41489]{1,0:T(8,128)} fusion(%a), kind=kLoop, calls=%f.1
+  %idx.2 = s32[75,41489]{1,0:T(8,128)} fusion(%a), kind=kLoop, calls=%f.2
+  %collective-permute-start = (f32[75,41489]{1,0}, f32[75,41489]{1,0}, u32[], u32[]) collective-permute-start(%vals), channel_id=1, source_target_pairs={{0,1},{1,2},{2,3},{3,0}}, metadata={op_name="jit(train_step)/obs:exchange/shard_map/mix/ppermute"}
+  %collective-permute-done = f32[75,41489]{1,0} collective-permute-done(%collective-permute-start)
+  %ppermute.3 = s32[75,41489]{1,0} collective-permute(%idx.2), channel_id=2, source_target_pairs={{0,3},{1,0},{2,1},{3,2}}
+  ROOT %copy.3 = f32[8]{0} copy(%a)
+}
+"""
+
+
+def test_mix_and_wire_readers():
+    mix = "jit(train_step)/obs:exchange/shard_map/mix"
+    ops = {"/device:TPU:0": [
+        _op(0, 40, "fusion", "jit(train_step)/obs:grad/dot"),
+        _op(40, 30, "collective-permute-done", mix + "/ppermute"),
+        _op(70, 10, "fusion", mix + "/scatter-add"),
+        _op(80, 20, "fusion", "jit(train_step)/obs:exchange/compress/x")],
+        "/device:TPU:1": [_op(0, 60, "collective-permute-done",
+                              mix + "/ppermute")]}
+    red = trace.Reduced(ops=ops, steps={d: [(0, 100)] for d in ops},
+                        host=[("bench:wait", 0, 100)], window=(0, 100))
+    ctx = trace.Context(cell=None, reduced=red, tokens_per_s=1.0,
+                        device_kind="TPU v5 lite", hlo=RING_HLO)
+    # the permutes, the densify and the weights, on the slowest chip
+    assert trace.read_metric("mix_ms", ctx) == 60 / 1e6
+    # values and indices of 75 rows x 41489 kept, once each way
+    assert trace.read_metric("wire_bytes_per_step", ctx) == \
+        2 * 4 * 75 * 41489
+    one_chip = trace.Context(cell=None, reduced=red, tokens_per_s=1.0,
+                             device_kind="TPU v5 lite", hlo=HLO)
+    assert trace.read_metric("wire_bytes_per_step", one_chip) is None
+    no_stages = trace.Reduced(ops={"/device:TPU:0": ops["/device:TPU:0"][:1]},
+                              steps=red.steps, host=red.host,
+                              window=red.window)
+    assert trace.read_metric("mix_ms", dataclasses.replace(
+        ctx, reduced=no_stages)) is None
+
+
 def _recorded():
     with open(os.path.join(DATA, "qwen3-1.7b.topk.1chip.result.json")) as f:
         return json.load(f)
@@ -98,11 +144,16 @@ def test_readers_reproduce_the_recorded_run():
     ctx = trace.Context(cell=cell, reduced=red,
                         tokens_per_s=tokens / window_s,
                         device_kind=result["device"]["kind"])
-    got = {m["name"]: trace.read_metric(m["name"], ctx)
-           for m in cell.per_layer}
-    want = {k: v["value"] for k, v in result["metrics"].items()}
-    assert {k: v for k, v in got.items() if v is not None} == \
-        pytest.approx(want, rel=1e-12)
+    # the metrics the cell declares that the recorded run printed: a reader
+    # retired since reads nothing here, and one added since has no
+    # recorded value to meet
+    declared = {m["name"] for m in cell.per_layer}
+    want = {k: v["value"] for k, v in result["metrics"].items()
+            if k in declared}
+    assert set(want) == {"host_gap_ms", "grad_ms", "step_mfu", "exchange_ms",
+                         "ef_update_roofline", "optimizer_ms"}
+    got = {k: trace.read_metric(k, ctx) for k in want}
+    assert got == pytest.approx(want, rel=1e-12)
     assert red.busy_s() == pytest.approx(result["device"]["busy_s"],
                                          rel=1e-12)
     assert trace.breakdown(red) == result["breakdown"]

@@ -69,6 +69,8 @@ class Context:
     reduced: Reduced
     tokens_per_s: float
     device_kind: str
+    #: the compiled step's HLO text
+    hlo: str = ""
 
 
 def xplane(trace_dir: str) -> str:
